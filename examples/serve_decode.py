@@ -84,6 +84,9 @@ def main():
                          "(loadable at ui.perfetto.dev)")
     args = ap.parse_args()
 
+    from repro.launch.cache import use_compile_cache
+
+    use_compile_cache()
     cfg = get_config(args.arch).reduced()
     print(f"arch={args.arch} (reduced: d={cfg.d_model}, L={cfg.n_layers}, "
           f"kind={cfg.layer_kind}, monarch={cfg.monarch.enable})")

@@ -71,12 +71,18 @@ def linear_apply(
     backend: str = "einsum",
 ) -> jax.Array:
     """y = x @ W (+ b).  Dispatches on the parameter structure (including
-    D2S-converted dense layers, where ``w`` becomes an {L, R} dict)."""
+    D2S-converted dense layers, where ``w`` becomes an {L, R} dict).
+
+    The product is computed in ``x.dtype`` whatever the weights are stored
+    in: weights are cast to the activation dtype (a no-op when they already
+    match), so a bf16 residual stream stays bf16 over fp32 master weights
+    and the MXU runs at activation precision."""
     if "w" in params and isinstance(params["w"], dict):
         inner = dict(params["w"])
         if "b" in params:
             inner["b"] = params["b"]
         return linear_apply(inner, x, precision=precision, backend=backend)
+    dt = x.dtype
     if qn.is_quantized(params):
         if backend == "pallas":
             from repro.kernels import ops as kops  # lazy: avoid cycle
@@ -86,18 +92,21 @@ def linear_apply(
         else:
             k = params["Ls"].shape[-3]
             deq = qn.dequantize_monarch(params, k, x.shape[-1] // k)
-            y = mn.monarch_multiply(x, deq["L"], deq["R"], precision=precision)
+            y = mn.monarch_multiply(x, deq["L"].astype(dt),
+                                    deq["R"].astype(dt), precision=precision)
     elif is_monarch(params):
+        L, R = params["L"].astype(dt), params["R"].astype(dt)
         if backend == "pallas":
             from repro.kernels import ops as kops  # lazy: avoid cycle
 
-            y = kops.monarch_mm(x, params["L"], params["R"])
+            y = kops.monarch_mm(x, L, R)
         else:
-            y = mn.monarch_multiply(x, params["L"], params["R"], precision=precision)
+            y = mn.monarch_multiply(x, L, R, precision=precision)
     else:
-        y = jnp.einsum("...d,df->...f", x, params["w"], precision=precision)
+        y = jnp.einsum("...d,df->...f", x, params["w"].astype(dt),
+                       precision=precision)
     if "b" in params:
-        y = y + params["b"]
+        y = y + params["b"].astype(dt)
     return y
 
 

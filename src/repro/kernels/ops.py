@@ -13,8 +13,10 @@ Backend/interpret and the VMEM-fit decision are resolved ONCE per
 loop calls these per projection per token step, so none of that should be
 recomputed per call.
 
-On CPU (this container) the kernels execute with ``interpret=True``; on TPU
-the same BlockSpecs compile through Mosaic.
+On a CPU backend the kernels execute with ``interpret=True``; on a TPU they
+compile through Mosaic.  ``tests/test_tpu_compile.py`` compiles each of them
+for a described v5e at real model widths — the interpret-mode tests cannot
+see tiling or VMEM refusals.
 """
 
 from __future__ import annotations
@@ -24,8 +26,9 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.bdmm import bdmm, bdmm_q
-from repro.kernels.monarch import (VMEM_BUDGET_BYTES, fused_fits,
+from repro.kernels.bdmm import bdmm, bdmm_kmajor, bdmm_q_kmajor
+from repro.kernels.monarch import (VMEM_BUDGET_BYTES, VMEM_LIMIT_BYTES,
+                                   fused_fits, fused_working_set,
                                    monarch_fused, monarch_fused_q)
 
 
@@ -36,15 +39,27 @@ def _interpret() -> bool:
 
 @functools.lru_cache(maxsize=None)
 def _dispatch(l_shape: tuple, r_shape: tuple, weight_bytes: float,
-              scale_bytes: int = 0,
-              dequant_bytes: float = 0) -> tuple[bool, bool]:
-    """(interpret, use_fused) for one (logical factor shapes, weight width)
-    key.  ``l_shape``/``r_shape`` are the UNPACKED shapes; ``weight_bytes``
-    is the stored width (4/2 float, 1 int8, 0.5 packed int4) and
-    ``dequant_bytes`` the in-kernel fp32 dequant temporaries of the
-    quantized path."""
-    return _interpret(), fused_fits(l_shape, r_shape, weight_bytes,
-                                    scale_bytes, dequant_bytes)
+              scale_bytes: int = 0, dequant_bytes: float = 0,
+              act_bytes: int = 4) -> tuple[bool, bool]:
+    """(interpret, use_fused) for one (logical factor shapes, weight width,
+    activation width) key.  ``l_shape``/``r_shape`` are the UNPACKED shapes;
+    ``weight_bytes`` is the stored width (4/2 float, 1 int8, 0.5 packed
+    int4) and ``dequant_bytes`` the in-kernel fp32 dequant temporaries of
+    the quantized path.  Fusion also needs the kernel's whole working set
+    (factors, token tiles, scratch) under the scoped VMEM it requests."""
+    fused = (fused_fits(l_shape, r_shape, weight_bytes, scale_bytes,
+                        dequant_bytes)
+             and fused_working_set(l_shape, r_shape, weight_bytes,
+                                   act_bytes) <= VMEM_LIMIT_BYTES)
+    return _interpret(), fused
+
+
+def _staged(xt, k, p, q, s, stage1, stage2):
+    """Two block-major ``bdmm`` stages with the folded permutation between
+    them: (T, k*p) -> (k, T, p) -> (k, T, q) -> (q, T, k) -> (q, T, s)."""
+    u = stage1(jnp.transpose(xt.reshape(-1, k, p), (1, 0, 2)))
+    y = stage2(jnp.transpose(u, (2, 1, 0)))
+    return jnp.transpose(y, (1, 0, 2)).reshape(-1, q * s)
 
 
 def monarch_mm(x: jax.Array, L: jax.Array, R: jax.Array) -> jax.Array:
@@ -53,13 +68,14 @@ def monarch_mm(x: jax.Array, L: jax.Array, R: jax.Array) -> jax.Array:
     k, q, p = L.shape
     _, s, _ = R.shape
     xt = x.reshape(-1, din)
-    interp, fused = _dispatch(L.shape, R.shape, L.dtype.itemsize)
+    interp, fused = _dispatch(L.shape, R.shape, L.dtype.itemsize,
+                              act_bytes=x.dtype.itemsize)
     if fused:
         y = monarch_fused(xt, L, R, interpret=interp)
-    else:  # staged: two bdmm calls + folded permutation (layout change)
-        u = bdmm(xt.reshape(-1, k, p), L, interpret=interp)   # (T, k, q)
-        ut = jnp.swapaxes(u, -1, -2)                          # (T, q, k)
-        y = bdmm(ut, R, interpret=interp).reshape(-1, q * s)  # (T, q, s)
+    else:
+        y = _staged(xt, k, p, q, s,
+                    lambda u: bdmm_kmajor(u, L, interpret=interp),
+                    lambda u: bdmm_kmajor(u, R, interpret=interp))
     return y.reshape(*batch, q * s)
 
 
@@ -75,16 +91,18 @@ def monarch_mm_q(x: jax.Array, Lq: jax.Array, Ls: jax.Array,
     packed = Lq.shape[-1] != p
     weight_bytes = 0.5 if packed else 1
     xt = x.reshape(-1, din)
-    # dequant_bytes=4: the fused kernel materializes fp32 copies of both
-    # factors in VMEM next to the pinned int8/int4 blocks
+    # dequant_bytes=4 counts an fp32 copy of every weight next to the
+    # pinned int8/int4 blocks; the kernel dequantizes one block at a time,
+    # so this bounds its temporaries from above
     interp, fused = _dispatch((k, q, p), (q, s, k), weight_bytes,
-                              scale_bytes=4 * (k + q), dequant_bytes=4)
+                              scale_bytes=4 * (k + q), dequant_bytes=4,
+                              act_bytes=x.dtype.itemsize)
     if fused:
         y = monarch_fused_q(xt, Lq, Ls, Rq, Rs, interpret=interp)
     else:
-        u = bdmm_q(xt.reshape(-1, k, p), Lq, Ls, interpret=interp)
-        ut = jnp.swapaxes(u, -1, -2)
-        y = bdmm_q(ut, Rq, Rs, interpret=interp).reshape(-1, q * s)
+        y = _staged(xt, k, p, q, s,
+                    lambda u: bdmm_q_kmajor(u, Lq, Ls, interpret=interp),
+                    lambda u: bdmm_q_kmajor(u, Rq, Rs, interpret=interp))
     return y.reshape(*batch, q * s)
 
 
@@ -102,8 +120,10 @@ def paged_span_fits(span: int, n_heads: int, head_dim: int,
     """Does one grid step of the paged-attention span kernel fit VMEM?
 
     Sums ONE grid step's working set against the same budget the Monarch
-    dispatch uses: the query span block, BOTH gathered k/v page blocks at
-    their **stored** width (``kv_bytes``: 4 fp32, 2 bf16, 1 int8), the
+    dispatch uses (the kernel asks the compiler for
+    ``kernels.paged.SPAN_VMEM_LIMIT_BYTES``, room for every block
+    double-buffered, so a "yes" here compiles): the query span block,
+    BOTH gathered k/v page blocks at their **stored** width (``kv_bytes``: 4 fp32, 2 bf16, 1 int8), the
     per-(page, head) KV scale rows plus the fp32 dequant temporaries of
     the quantized path (``scale_bytes`` > 0 flags it — the kernel
     materializes fp32 copies of both pages next to the pinned int8

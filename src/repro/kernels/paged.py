@@ -26,12 +26,15 @@ dequant is the same single op the host-side oracle runs, so the quantized
 kernel is bitwise-identical to the fp32 kernel fed pre-dequantized pages.
 
 Grid: (B, MP).  Scalar prefetch: page_table (B, MP), start (B,),
-span_len (B,), window (1,).  Scratch: per-(span, head) running max /
-normalizer / accumulator, persistent across the MP inner steps of one
-sequence.
+span_len (B,), window (1,).  Blocks fold the heads into the lane axis:
+q/out (1, S, H*hd), a page (1, page, KV*hd), its scales (1, 1, KV); the
+body walks the heads as static lane slices, so every op is 2-D.  Scratch:
+per-(head, span) running max / normalizer and the (S, H*hd) accumulator,
+persistent across the MP inner steps of one sequence.
 
-On CPU (this container) the kernel executes with ``interpret=True``; on TPU
-the same BlockSpecs compile through Mosaic.
+On a CPU backend the kernel executes with ``interpret=True``; on a TPU it
+compiles through Mosaic (``tests/test_tpu_compile.py`` compiles it for a
+described v5e at real widths).
 """
 
 from __future__ import annotations
@@ -45,148 +48,154 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+# scoped VMEM the span kernel asks the compiler for: twice the fit budget
+# ``kernels.ops.paged_span_fits`` admits (the pipeline double-buffers every
+# block) plus the per-head temporaries; v5e has 128 MiB of VMEM per core
+SPAN_VMEM_LIMIT_BYTES = 32 * 2**20
 
-def _span_attend(b, i, st_ref, sp_ref, win_ref, q, k, v,
-                 o_ref, m_ref, l_ref, acc_ref, *, page_size: int):
+
+def _span_attend(b, i, st_ref, sp_ref, win_ref, q_ref, kv_head,
+                 o_ref, m_ref, l_ref, acc_ref, *, page_size: int,
+                 n_heads: int, n_kv_heads: int):
     """One flash step over a single (sequence, page) grid cell: fold the
-    fp32 page ``k``/``v`` into the running softmax for span queries ``q``."""
+    page into the running softmax of every query head.
+
+    Blocks are 2-D slabs with heads packed on the lane axis — q/out
+    (S, H*hd), a page (page, KV*hd) — and each head is a static lane slice,
+    so every op is a 2-D dot, a row reduction or an elementwise op.
+    ``kv_head(j)`` returns KV head ``j`` of the page as fp32 (k, v), each
+    (page, hd)."""
     @pl.when(i == 0)
     def _init():
         m_ref[:] = jnp.full_like(m_ref, -1e30)
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    S, H, hd = q.shape
-    pg, KV, _ = k.shape
-    g = H // KV
+    S = q_ref.shape[1]
+    hd = q_ref.shape[2] // n_heads
+    g = n_heads // n_kv_heads
+    pg = page_size
+    t = i * pg + jax.lax.broadcasted_iota(jnp.int32, (S, pg), 1)
+    q_pos = st_ref[b] + jax.lax.broadcasted_iota(jnp.int32, (S, pg), 0)
+    ok = (t <= q_pos) & ((q_pos - t) < win_ref[0])           # (S, pg)
+    okf = ok.astype(jnp.float32)
 
-    qh = q.reshape(S, KV, g, hd)
-    s = jnp.einsum("skgh,tkh->skgt", qh, k) / math.sqrt(hd)  # (S,KV,g,pg)
-    t = i * page_size + jnp.arange(pg)
-    q_pos = st_ref[b] + jnp.arange(S)                        # (S,)
-    ok = (t[None, :] <= q_pos[:, None]) \
-        & ((q_pos[:, None] - t[None, :]) < win_ref[0])       # (S, pg)
-    s = jnp.where(ok[:, None, None, :], s, -1e30).reshape(S, H, pg)
-
-    m_prev = m_ref[:]                                        # (S, H)
-    l_prev = l_ref[:]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-    # explicit ok-multiply: a fully-masked page would otherwise contribute
-    # exp(-1e30 - (-1e30)) = 1 per key to the normalizer
-    p = jnp.exp(s - m_new[..., None]) * ok[:, None, :].astype(jnp.float32)
-    scale = jnp.exp(m_prev - m_new)
-    l_ref[:] = l_prev * scale + jnp.sum(p, axis=-1)
-    pv = jnp.einsum("skgt,tkh->skgh", p.reshape(S, KV, g, pg), v)
-    acc_ref[:] = acc_ref[:] * scale[..., None] + pv.reshape(S, H, hd)
-    m_ref[:] = m_new
+    for j in range(n_kv_heads):
+        k, v = kv_head(j)
+        for h in range(j * g, (j + 1) * g):
+            cols = slice(h * hd, (h + 1) * hd)
+            q = q_ref[0, :, cols].astype(jnp.float32)            # (S, hd)
+            s = jax.lax.dot_general(
+                q, k, dimension_numbers=(((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) / math.sqrt(hd)
+            s = jnp.where(ok, s, -1e30)                         # (S, pg)
+            m_prev = m_ref[h]                                   # (S, 1)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            # explicit ok-multiply: a fully-masked page would otherwise
+            # contribute exp(-1e30 - (-1e30)) = 1 per key to the normalizer
+            p = jnp.exp(s - m_new) * okf
+            scale = jnp.exp(m_prev - m_new)
+            l_ref[h] = l_ref[h] * scale + jnp.sum(p, axis=-1, keepdims=True)
+            pv = jnp.dot(p, v, preferred_element_type=jnp.float32)
+            acc_ref[:, cols] = acc_ref[:, cols] * scale + pv
+            m_ref[h] = m_new
 
     @pl.when(i == pl.num_programs(1) - 1)
     def _emit():
-        out = acc_ref[:] / jnp.maximum(l_ref[:], 1e-30)[..., None]
-        valid = (jnp.arange(S) < sp_ref[b])[:, None, None]
-        o_ref[0] = jnp.where(valid, out, 0.0).astype(o_ref.dtype)
+        valid = jax.lax.broadcasted_iota(jnp.int32, (S, 1), 0) < sp_ref[b]
+        for h in range(n_heads):
+            cols = slice(h * hd, (h + 1) * hd)
+            out = acc_ref[:, cols] / jnp.maximum(l_ref[h], 1e-30)
+            o_ref[0, :, cols] = jnp.where(valid, out, 0.0).astype(o_ref.dtype)
 
 
 def _paged_span_kernel(pt_ref, st_ref, sp_ref, win_ref, q_ref, k_ref, v_ref,
-                       o_ref, m_ref, l_ref, acc_ref, *, page_size: int):
-    b = pl.program_id(0)
-    i = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32)       # (S, H, hd)
-    k = k_ref[0].astype(jnp.float32)       # (pg, KV, hd)
-    v = v_ref[0].astype(jnp.float32)
-    _span_attend(b, i, st_ref, sp_ref, win_ref, q, k, v,
-                 o_ref, m_ref, l_ref, acc_ref, page_size=page_size)
+                       o_ref, m_ref, l_ref, acc_ref, *, page_size: int,
+                       n_heads: int, n_kv_heads: int):
+    hd = k_ref.shape[2] // n_kv_heads
+
+    def kv_head(j):
+        cols = slice(j * hd, (j + 1) * hd)
+        return (k_ref[0, :, cols].astype(jnp.float32),
+                v_ref[0, :, cols].astype(jnp.float32))
+
+    _span_attend(pl.program_id(0), pl.program_id(1), st_ref, sp_ref,
+                 win_ref, q_ref, kv_head, o_ref, m_ref, l_ref, acc_ref,
+                 page_size=page_size, n_heads=n_heads, n_kv_heads=n_kv_heads)
 
 
 def _paged_span_kernel_q(pt_ref, st_ref, sp_ref, win_ref, q_ref, k_ref,
                          v_ref, ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref,
-                         *, page_size: int):
-    b = pl.program_id(0)
-    i = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32)                          # (S, H, hd)
-    # in-VMEM dequant: int8 page x its (KV,) per-(page, head) scale row —
-    # the same cast-multiply as core.quant.dequantize_kv_pages, so the
-    # result is bitwise what the fp32 kernel sees on dequantized pages
-    k = k_ref[0].astype(jnp.float32) * ks_ref[0][None, :, None]
-    v = v_ref[0].astype(jnp.float32) * vs_ref[0][None, :, None]
-    _span_attend(b, i, st_ref, sp_ref, win_ref, q, k, v,
-                 o_ref, m_ref, l_ref, acc_ref, page_size=page_size)
+                         *, page_size: int, n_heads: int, n_kv_heads: int):
+    hd = k_ref.shape[2] // n_kv_heads
+
+    def kv_head(j):
+        # in-VMEM dequant: int8 rows x the page's per-head scale — the same
+        # cast-multiply as core.quant.dequantize_kv_pages, so the result is
+        # bitwise what the fp32 kernel sees on dequantized pages
+        cols = slice(j * hd, (j + 1) * hd)
+        return (k_ref[0, :, cols].astype(jnp.float32) * ks_ref[0, :, j:j + 1],
+                v_ref[0, :, cols].astype(jnp.float32) * vs_ref[0, :, j:j + 1])
+
+    _span_attend(pl.program_id(0), pl.program_id(1), st_ref, sp_ref,
+                 win_ref, q_ref, kv_head, o_ref, m_ref, l_ref, acc_ref,
+                 page_size=page_size, n_heads=n_heads, n_kv_heads=n_kv_heads)
+
+
+def _span_call(kernel, q, k_pages, v_pages, scales, page_table, start,
+               span_len, window, interpret):
+    """Shared pallas_call: heads fold into the lane axis (free reshapes of
+    the contiguous q / page buffers), per-(page, head) scales ride as
+    (P, 1, KV) rows next to their page."""
+    B, S, H, hd = q.shape
+    P, pg, KV, _ = k_pages.shape
+    MP = page_table.shape[1]
+    page = pl.BlockSpec((1, pg, KV * hd),
+                        lambda b, i, pt, st, sp, wn: (pt[b, i], 0, 0))
+    scale = pl.BlockSpec((1, 1, KV),
+                         lambda b, i, pt, st, sp, wn: (pt[b, i], 0, 0))
+    span = pl.BlockSpec((1, S, H * hd),
+                        lambda b, i, pt, st, sp, wn: (b, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(B, MP),
+        in_specs=[span, page, page] + [scale] * len(scales),
+        out_specs=span,
+        scratch_shapes=[
+            pltpu.VMEM((H, S, 1), jnp.float32),
+            pltpu.VMEM((H, S, 1), jnp.float32),
+            pltpu.VMEM((S, H * hd), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(kernel, page_size=pg, n_heads=H, n_kv_heads=KV),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, S, H * hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=SPAN_VMEM_LIMIT_BYTES),
+        interpret=interpret,
+    )(page_table.astype(jnp.int32), start.astype(jnp.int32),
+      span_len.astype(jnp.int32), window.reshape(1).astype(jnp.int32),
+      q.reshape(B, S, H * hd), k_pages.reshape(P, pg, KV * hd),
+      v_pages.reshape(P, pg, KV * hd),
+      *[sc.astype(jnp.float32).reshape(P, 1, KV) for sc in scales])
+    return out.reshape(B, S, H, hd)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _paged_attention_span(q, k_pages, v_pages, page_table, start, span_len,
                           window, *, interpret: bool):
-    B, S, H, hd = q.shape
-    _, pg, KV, _ = k_pages.shape
-    MP = page_table.shape[1]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(B, MP),
-        in_specs=[
-            pl.BlockSpec((1, S, H, hd),
-                         lambda b, i, pt, st, sp, wn: (b, 0, 0, 0)),
-            pl.BlockSpec((1, pg, KV, hd),
-                         lambda b, i, pt, st, sp, wn: (pt[b, i], 0, 0, 0)),
-            pl.BlockSpec((1, pg, KV, hd),
-                         lambda b, i, pt, st, sp, wn: (pt[b, i], 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, S, H, hd),
-                               lambda b, i, pt, st, sp, wn: (b, 0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((S, H), jnp.float32),
-            pltpu.VMEM((S, H), jnp.float32),
-            pltpu.VMEM((S, H, hd), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        functools.partial(_paged_span_kernel, page_size=pg),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, S, H, hd), q.dtype),
-        interpret=interpret,
-    )(page_table.astype(jnp.int32), start.astype(jnp.int32),
-      span_len.astype(jnp.int32), window.reshape(1).astype(jnp.int32),
-      q, k_pages, v_pages)
+    return _span_call(_paged_span_kernel, q, k_pages, v_pages, (),
+                      page_table, start, span_len, window, interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _paged_attention_span_q(q, k_pages, v_pages, k_scales, v_scales,
                             page_table, start, span_len, window, *,
                             interpret: bool):
-    B, S, H, hd = q.shape
-    _, pg, KV, _ = k_pages.shape
-    MP = page_table.shape[1]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(B, MP),
-        in_specs=[
-            pl.BlockSpec((1, S, H, hd),
-                         lambda b, i, pt, st, sp, wn: (b, 0, 0, 0)),
-            pl.BlockSpec((1, pg, KV, hd),
-                         lambda b, i, pt, st, sp, wn: (pt[b, i], 0, 0, 0)),
-            pl.BlockSpec((1, pg, KV, hd),
-                         lambda b, i, pt, st, sp, wn: (pt[b, i], 0, 0, 0)),
-            pl.BlockSpec((1, KV),
-                         lambda b, i, pt, st, sp, wn: (pt[b, i], 0)),
-            pl.BlockSpec((1, KV),
-                         lambda b, i, pt, st, sp, wn: (pt[b, i], 0)),
-        ],
-        out_specs=pl.BlockSpec((1, S, H, hd),
-                               lambda b, i, pt, st, sp, wn: (b, 0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((S, H), jnp.float32),
-            pltpu.VMEM((S, H), jnp.float32),
-            pltpu.VMEM((S, H, hd), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        functools.partial(_paged_span_kernel_q, page_size=pg),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, S, H, hd), q.dtype),
-        interpret=interpret,
-    )(page_table.astype(jnp.int32), start.astype(jnp.int32),
-      span_len.astype(jnp.int32), window.reshape(1).astype(jnp.int32),
-      q, k_pages, v_pages, k_scales.astype(jnp.float32),
-      v_scales.astype(jnp.float32))
+    return _span_call(_paged_span_kernel_q, q, k_pages, v_pages,
+                      (k_scales, v_scales), page_table, start, span_len,
+                      window, interpret)
 
 
 def _interpret() -> bool:
@@ -264,9 +273,8 @@ def paged_attention_span_sharded(q: jax.Array, k_pages: jax.Array,
     caller gates on that — GQA-replicated pools stay on the dense path).
     Mesh axes other than ``axis`` (the "data" axis) are untouched: inputs
     are replicated over them and each slice computes identical outputs.
-    ``check_rep=False`` because pallas_call defeats shard_map's replication
+    ``check_vma=False`` because pallas_call defeats shard_map's replication
     checker, not because anything is unreplicated."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     if (k_scales is None) != (v_scales is None):
@@ -286,10 +294,10 @@ def paged_attention_span_sharded(q: jax.Array, k_pages: jax.Array,
             return _paged_attention_span_q(q, kp, vp, ks, vs, pt, st, sp,
                                            wn, interpret=interp)
 
-        fn = shard_map(body, mesh=mesh,
-                       in_specs=(heads, heads, heads, P(None, axis),
-                                 P(None, axis), rep, rep, rep, rep),
-                       out_specs=heads, check_rep=False)
+        fn = jax.shard_map(body, mesh=mesh,
+                           in_specs=(heads, heads, heads, P(None, axis),
+                                     P(None, axis), rep, rep, rep, rep),
+                           out_specs=heads, check_vma=False)
         return fn(q, k_pages, v_pages, k_scales, v_scales,
                   page_table.astype(jnp.int32), start.astype(jnp.int32),
                   span_len.astype(jnp.int32), win)
@@ -298,9 +306,9 @@ def paged_attention_span_sharded(q: jax.Array, k_pages: jax.Array,
         return _paged_attention_span(q, kp, vp, pt, st, sp, wn,
                                      interpret=interp)
 
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(heads, heads, heads, rep, rep, rep, rep),
-                   out_specs=heads, check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(heads, heads, heads, rep, rep, rep, rep),
+                       out_specs=heads, check_vma=False)
     return fn(q, k_pages, v_pages, page_table.astype(jnp.int32),
               start.astype(jnp.int32), span_len.astype(jnp.int32), win)
 

@@ -3,13 +3,16 @@ os.environ["XLA_FLAGS"] = (
     os.environ.get("XLA_FLAGS", "")
     + " --xla_force_host_platform_device_count=512"
 )
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: AOT lower+compile every (arch x shape x mesh) cell.
 
-The two lines above run before ANY other import (jax locks the device count
+The lines above run before ANY other import (jax locks the device count
 at first init): the dry-run — and only the dry-run — sees 512 placeholder
 host devices so ``jax.make_mesh`` can build the production meshes
-(16x16 single-pod, 2x16x16 multi-pod).
+(16x16 single-pod, 2x16x16 multi-pod).  It is pinned to the CPU platform,
+and so are the per-cell children it starts: a placeholder-device tool must
+never take an accelerator that another process is using.
 
 Per cell this produces:
   * ``compiled.memory_analysis()``  (fits-on-chip proof)
@@ -394,7 +397,8 @@ def _run_all(resume: bool, variant: str, multi_pod_only: bool,
         print(f"[dryrun] {out.stem} ...", flush=True)
         t0 = time.time()
         r = subprocess.run(cmd, capture_output=True, text=True, timeout=3600,
-                           env={**os.environ, "PYTHONPATH": "src"})
+                           env={**os.environ, "PYTHONPATH": "src",
+                                "JAX_PLATFORMS": "cpu"})
         dt = time.time() - t0
         if r.returncode != 0 and not out.exists():
             failed += 1

@@ -17,12 +17,21 @@ parallelism within an ICI domain.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto(n: int) -> tuple:
+    """GSPMD-propagated ("Auto") axes: the partitioner places every
+    intermediate from the parameter/pool shardings plus the ``logical()``
+    constraints.  ``jax.make_mesh`` defaults to Explicit axes, under which
+    gathers such as the embedding lookup need per-op output shardings."""
+    return (AxisType.Auto,) * n
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_host_mesh(*, model: int | None = None,
@@ -57,7 +66,8 @@ def make_host_mesh(*, model: int | None = None,
         raise ValueError(
             f"mesh ({data} data x {model} model) = {model * data} devices, "
             f"but {n} are visible — axes must multiply to the device count")
-    return jax.make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=_auto(2))
 
 
 __all__ = ["make_production_mesh", "make_host_mesh"]

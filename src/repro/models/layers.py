@@ -631,7 +631,7 @@ def embedding_init(key, cfg: ModelConfig) -> dict:
 
 
 def embed(params: dict, tokens: jax.Array, cfg: ModelConfig, dtype) -> jax.Array:
-    x = params["table"].astype(dtype)[tokens]
+    x = params["table"][tokens].astype(dtype)  # gather rows, then cast
     x = x * math.sqrt(cfg.d_model) if cfg.norm_type == "rmsnorm" else x
     return logical(x, "batch", "seq", "embed")
 

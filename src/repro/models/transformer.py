@@ -291,6 +291,22 @@ def init_params(key, cfg: ModelConfig) -> dict:
     return p
 
 
+def serving_params(params, cfg: ModelConfig) -> dict:
+    """Floating weights cast to the activation dtype (``cfg.dtype``): how
+    the serving engine stores them.  A bf16 config keeps 2-byte weights on
+    device; an fp32 config comes back unchanged.  Per-block quantization
+    scales (``Ls``/``Rs``) keep their fp32 width."""
+    dt = _dtype(cfg)
+
+    def cast(path, a):
+        if (getattr(path[-1], "key", None) in ("Ls", "Rs")
+                or not jnp.issubdtype(a.dtype, jnp.floating)):
+            return a
+        return a.astype(dt)
+
+    return jax.tree_util.tree_map_with_path(cast, params)
+
+
 def _encode(params, batch, cfg: ModelConfig, train: bool):
     """Encoder pass (bidirectional).  The audio frontend is a stub: the
     batch carries precomputed frame embeddings (DESIGN.md Sec. 6)."""
@@ -488,7 +504,7 @@ def paged_mixed_step(params, tokens: jax.Array, start: jax.Array,
 
 
 __all__ = [
-    "init_params", "forward", "loss_fn",
+    "init_params", "serving_params", "forward", "loss_fn",
     "init_decode_cache", "decode_step", "prefill", "prefill_with_cache",
     "init_paged_pool", "paged_mixed_step", "cow_copy_pages",
     "decoder_stack_init", "decoder_stack_apply",

@@ -86,13 +86,19 @@ class DeviceKV:
         self.page_size = page_size
         self.mesh = mesh
         self.kv_shard = kv_shard_size(cfg, mesh)
-        pool = T.init_paged_pool(cfg, n_pages, page_size, kv_dtype=kv_dtype)
+        def init():
+            return T.init_paged_pool(cfg, n_pages, page_size,
+                                     kv_dtype=kv_dtype)
+
         if mesh is not None:
-            self.shardings = pool_shardings(pool, mesh, self.kv_shard)
-            pool = jax.device_put(pool, self.shardings)
+            # made on its shards: the logical pool of a tp engine is sized
+            # per shard and need not fit one device whole
+            self.shardings = pool_shardings(jax.eval_shape(init), mesh,
+                                            self.kv_shard)
+            self.pool = jax.jit(init, out_shardings=self.shardings)()
         else:
             self.shardings = None
-        self.pool = pool
+            self.pool = init()
 
     # -- snapshot transfer -------------------------------------------------
 

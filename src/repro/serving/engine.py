@@ -264,6 +264,11 @@ class ContinuousBatchingEngine:
         # (weight bytes), not CPU wall clock.
         from repro.core.quant import BITS_BY_NAME
 
+        # served weights live in the activation dtype: a bf16 config holds
+        # 2-byte weights on device and multiplies at the MXU's bf16 rate
+        # (an fp32 config is unchanged).  Cast before fusion/quantization,
+        # so int8 scales are taken from the weights actually served.
+        params = T.serving_params(params, cfg)
         if quantize is not None and quantize not in BITS_BY_NAME:
             raise ValueError(
                 f"quantize must be one of {sorted(BITS_BY_NAME)} or None, "

@@ -14,8 +14,8 @@ from repro.core import monarch as mn
 from repro.core import quant as qn
 from repro.core.linear import MonarchSpec, linear_apply, linear_init, linear_out_dim
 from repro.kernels import ops
-from repro.kernels.bdmm import bdmm_q
-from repro.kernels.monarch import fused_fits, monarch_fused_q
+from repro.kernels.bdmm import bdmm, bdmm_q
+from repro.kernels.monarch import fused_fits, monarch_fused, monarch_fused_q
 from repro.kernels.ref import bdmm_q_ref, monarch_q_ref, monarch_ref
 from repro.models import decode_path as DP
 from repro.models import fuse as F
@@ -30,6 +30,22 @@ CFG = ModelConfig(name="tq", d_model=128, n_layers=2, n_heads=4,
 def _monarch_params(key=0, din=256, dout=512, k=16, q=16):
     dims = mn.MonarchDims(din=din, dout=dout, k=k, q=q)
     return mn.init_monarch(jax.random.PRNGKey(key), dims)
+
+
+# fp32 summation order: a kernel contracts one diagonal block per 2-D dot,
+# an oracle runs one batched einsum, and a fused projection one wider
+# matmul.  On the CPU backend the orders differ by a few units in the last
+# place of the output's scale (measured: <= 2e-6 at magnitudes ~5), so those
+# comparisons allow ORACLE_ULPS of it.  Exactness stays asserted where the
+# code guarantees it: in-VMEM dequant against the float kernel fed the
+# dequantized factors.
+ORACLE_ULPS = 16
+
+
+def _assert_ulp_close(got, want, ulps=ORACLE_ULPS):
+    want = np.asarray(want, np.float32)
+    atol = ulps * np.finfo(np.float32).eps * float(np.abs(want).max())
+    np.testing.assert_allclose(np.asarray(got), want, rtol=0, atol=atol)
 
 
 # ---------------------------------------------------------------------------
@@ -82,8 +98,13 @@ def test_quantize_monarch_container_shapes():
 
 
 # ---------------------------------------------------------------------------
-# kernels: in-VMEM dequant matches the dequantize-then-einsum oracle EXACTLY
+# kernels: in-VMEM dequant is EXACTLY the dequantize-then-multiply chain
 # ---------------------------------------------------------------------------
+
+
+def _dequantized(qp, k, p):
+    deq = qn.dequantize_monarch(qp, k, p)
+    return deq["L"], deq["R"]
 
 
 @pytest.mark.parametrize("bits", [8, 4])
@@ -92,8 +113,10 @@ def test_bdmm_q_matches_oracle_exactly(bits):
     qp = qn.quantize_monarch(p, bits)
     x = jax.random.normal(jax.random.PRNGKey(2), (40, 16, 16))
     got = bdmm_q(x, qp["Lq"], qp["Ls"], interpret=True)
-    want = bdmm_q_ref(x, qp["Lq"], qp["Ls"])
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    L, _ = _dequantized(qp, 16, 16)
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.asarray(bdmm(x, L, interpret=True)))
+    _assert_ulp_close(got, bdmm_q_ref(x, qp["Lq"], qp["Ls"]))
 
 
 @pytest.mark.parametrize("bits", [8, 4])
@@ -104,8 +127,11 @@ def test_monarch_fused_q_matches_oracle_exactly(bits, T_):
     x = jax.random.normal(jax.random.PRNGKey(3), (T_, 256))
     got = monarch_fused_q(x, qp["Lq"], qp["Ls"], qp["Rq"], qp["Rs"],
                           interpret=True)
-    want = monarch_q_ref(x, qp["Lq"], qp["Ls"], qp["Rq"], qp["Rs"])
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    L, R = _dequantized(qp, 16, 16)
+    np.testing.assert_array_equal(
+        np.asarray(got), np.asarray(monarch_fused(x, L, R, interpret=True)))
+    _assert_ulp_close(got, monarch_q_ref(x, qp["Lq"], qp["Ls"], qp["Rq"],
+                                         qp["Rs"]))
 
 
 @pytest.mark.parametrize("bits", [8, 4])
@@ -118,8 +144,10 @@ def test_ops_monarch_mm_q_and_linear_apply(bits):
     y_pallas = ops.monarch_mm_q(x, qp["Lq"], qp["Ls"], qp["Rq"], qp["Rs"])
     y_einsum = linear_apply(qp, x)
     assert y_pallas.shape == (2, 5, 512)
-    np.testing.assert_array_equal(np.asarray(y_pallas.reshape(10, 512)),
-                                  np.asarray(want))
+    L, R = _dequantized(qp, 16, 16)
+    np.testing.assert_array_equal(np.asarray(y_pallas),
+                                  np.asarray(ops.monarch_mm(x, L, R)))
+    _assert_ulp_close(y_pallas.reshape(10, 512), want)
     np.testing.assert_array_equal(np.asarray(y_einsum.reshape(10, 512)),
                                   np.asarray(want))
     assert linear_out_dim(qp) == 512
@@ -192,7 +220,7 @@ def test_fuse_model_decode_step_bitwise():
     tok = jnp.array([3, 5], dtype=jnp.int32)
     lo1, _ = T.decode_step(params, tok, T.init_decode_cache(CFG, 2, 16), CFG)
     lo2, _ = T.decode_step(fused, tok, T.init_decode_cache(CFG, 2, 16), CFG)
-    np.testing.assert_array_equal(np.asarray(lo1), np.asarray(lo2))
+    _assert_ulp_close(lo2, lo1)
 
 
 def test_fuse_model_gqa_fuses_kv_only():
@@ -204,7 +232,7 @@ def test_fuse_model_gqa_fuses_kv_only():
     tok = jnp.array([7, 9], dtype=jnp.int32)
     lo1, _ = T.decode_step(params, tok, T.init_decode_cache(cfg, 2, 16), cfg)
     lo2, _ = T.decode_step(fused, tok, T.init_decode_cache(cfg, 2, 16), cfg)
-    np.testing.assert_array_equal(np.asarray(lo1), np.asarray(lo2))
+    _assert_ulp_close(lo2, lo1)
 
 
 def test_fuse_model_encdec_cross_attention_fuses_kv_only():
@@ -218,7 +246,7 @@ def test_fuse_model_encdec_cross_attention_fuses_kv_only():
              "enc_tokens": jnp.zeros((2, 5), jnp.int32)}
     lo1, _ = T.forward(params, batch, cfg, train=False)
     lo2, _ = T.forward(fused, batch, cfg, train=False)
-    np.testing.assert_array_equal(np.asarray(lo1), np.asarray(lo2))
+    _assert_ulp_close(lo2, lo1)
 
 
 def test_fused_proj_init_and_forward():

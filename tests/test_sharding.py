@@ -14,90 +14,93 @@ from jax.sharding import AbstractMesh, PartitionSpec as P
 from repro.models.config import ModelConfig
 from repro.sharding.params import spec_for
 
-MESH8 = AbstractMesh((("data", 1), ("model", 8)))
+
+@pytest.fixture(scope="module")
+def mesh8():
+    return AbstractMesh((1, 8), ("data", "model"))
 
 
 # -- suffix-rule matching on nested paths ------------------------------------
 
-def test_attention_projections_megatron_pair():
+def test_attention_projections_megatron_pair(mesh8):
     # column-parallel in, row-parallel out: one all-reduce per layer
-    assert spec_for("decoder/layers/attn/wq/w", (128, 128), MESH8) == \
+    assert spec_for("decoder/layers/attn/wq/w", (128, 128), mesh8) == \
         P("data", "model")
-    assert spec_for("decoder/layers/attn/wv/w", (128, 128), MESH8) == \
+    assert spec_for("decoder/layers/attn/wv/w", (128, 128), mesh8) == \
         P("data", "model")
-    assert spec_for("decoder/layers/attn/wo/w", (128, 128), MESH8) == \
+    assert spec_for("decoder/layers/attn/wo/w", (128, 128), mesh8) == \
         P("model", "data")
 
 
-def test_monarch_factor_rules_win_over_projection_rules():
+def test_monarch_factor_rules_win_over_projection_rules(mesh8):
     # "/L" precedes ("wq", "w") in the rule list, so a Monarch factor under
     # an attention projection shards as a factor (stage-1 block-rows over
     # "model"), not as a dense weight
-    assert spec_for("decoder/layers/attn/wq/L", (8, 16, 16), MESH8) == \
+    assert spec_for("decoder/layers/attn/wq/L", (8, 16, 16), mesh8) == \
         P("model", None, "data")
-    assert spec_for("decoder/layers/attn/wq/R", (16, 16, 8), MESH8) == \
+    assert spec_for("decoder/layers/attn/wq/R", (16, 16, 8), mesh8) == \
         P(None, "data", "model")
 
 
-def test_fused_keys_ride_existing_rules_by_substring():
+def test_fused_keys_ride_existing_rules_by_substring(mesh8):
     # fuse.py emits wqkv / wkv / w1g — substring containment means they hit
     # the wq / wk / w1 rules without fusion-specific entries
-    assert spec_for("decoder/layers/attn/wqkv/w", (128, 384), MESH8) == \
+    assert spec_for("decoder/layers/attn/wqkv/w", (128, 384), mesh8) == \
         P("data", "model")
-    assert spec_for("decoder/layers/attn/wkv/w", (128, 256), MESH8) == \
+    assert spec_for("decoder/layers/attn/wkv/w", (128, 256), mesh8) == \
         P("data", "model")
-    assert spec_for("decoder/layers/ffn/w1g/w", (128, 512), MESH8) == \
+    assert spec_for("decoder/layers/ffn/w1g/w", (128, 512), mesh8) == \
         P("data", "model")
 
 
-def test_embedding_rules():
-    assert spec_for("embedding/table", (512, 128), MESH8) == \
+def test_embedding_rules(mesh8):
+    assert spec_for("embedding/table", (512, 128), mesh8) == \
         P("model", "data")
-    assert spec_for("embedding/unembed", (128, 512), MESH8) == \
+    assert spec_for("embedding/unembed", (128, 512), mesh8) == \
         P("data", "model")
 
 
-def test_unmatched_paths_replicate():
-    assert spec_for("decoder/layers/ln1/scale", (128,), MESH8) == P()
-    assert spec_for("ln_f/scale", (128,), MESH8) == P()
+def test_unmatched_paths_replicate(mesh8):
+    assert spec_for("decoder/layers/ln1/scale", (128,), mesh8) == P()
+    assert spec_for("ln_f/scale", (128,), mesh8) == P()
 
 
 # -- leading-dim None padding -------------------------------------------------
 
-def test_layer_stacked_leaves_pad_leading_dims():
+def test_layer_stacked_leaves_pad_leading_dims(mesh8):
     # vmap-initialized trees carry a leading layer axis the trailing-dim
     # rules never name: it must pad to None, not shift the spec
-    assert spec_for("decoder/layers/attn/wq/w", (4, 128, 128), MESH8) == \
+    assert spec_for("decoder/layers/attn/wq/w", (4, 128, 128), mesh8) == \
         P(None, "data", "model")
-    assert spec_for("decoder/layers/attn/wq/L", (4, 8, 16, 16), MESH8) == \
+    assert spec_for("decoder/layers/attn/wq/L", (4, 8, 16, 16), mesh8) == \
         P(None, "model", None, "data")
 
 
-def test_rule_longer_than_shape_replicates():
+def test_rule_longer_than_shape_replicates(mesh8):
     # a scalar-ish leaf that happens to match a multi-dim rule replicates
     # instead of raising
-    assert spec_for("decoder/layers/attn/wo/w", (128,), MESH8) == P()
+    assert spec_for("decoder/layers/attn/wo/w", (128,), mesh8) == P()
 
 
 # -- divisibility guard -------------------------------------------------------
 
-def test_minicpm_vocab_stays_unsharded_on_8way_axis():
+def test_minicpm_vocab_stays_unsharded_on_8way_axis(mesh8):
     # 122753 is prime-ish w.r.t. 8: the vocab dim must stay replicated
     # while the d_model dim keeps its axis
-    assert spec_for("embedding/unembed", (128, 122753), MESH8) == \
+    assert spec_for("embedding/unembed", (128, 122753), mesh8) == \
         P("data", None)
-    assert spec_for("embedding/table", (122753, 128), MESH8) == \
+    assert spec_for("embedding/table", (122753, 128), mesh8) == \
         P(None, "data")
 
 
-def test_divisibility_guard_is_per_dim():
+def test_divisibility_guard_is_per_dim(mesh8):
     # only the offending dim drops its axis, others keep theirs
-    assert spec_for("decoder/layers/attn/wq/w", (128, 129), MESH8) == \
+    assert spec_for("decoder/layers/attn/wq/w", (128, 129), mesh8) == \
         P("data", None)
 
 
 def test_missing_mesh_axis_drops_to_none():
-    mesh = AbstractMesh((("model", 8),))  # no "data" axis at all
+    mesh = AbstractMesh((8,), ("model",))  # no "data" axis at all
     assert spec_for("decoder/layers/attn/wq/w", (128, 128), mesh) == \
         P(None, "model")
 
@@ -122,7 +125,7 @@ def test_make_host_mesh_model_1_always_works():
 
 # -- kv shard sizing ----------------------------------------------------------
 
-def test_kv_shard_size_divisibility():
+def test_kv_shard_size_divisibility(mesh8):
     from repro.serving.device_kv import kv_shard_size
 
     gqa = ModelConfig(name="t", d_model=128, n_layers=2, n_heads=8,
@@ -130,9 +133,9 @@ def test_kv_shard_size_divisibility():
     mha = ModelConfig(name="t", d_model=128, n_layers=2, n_heads=8,
                       n_kv_heads=8, d_ff=256, vocab=512, dtype="float32")
     assert kv_shard_size(mha, None) == 1
-    assert kv_shard_size(mha, MESH8) == 8
+    assert kv_shard_size(mha, mesh8) == 8
     # 2 KV heads on an 8-way model axis: replicated, never uneven
-    assert kv_shard_size(gqa, MESH8) == 1
+    assert kv_shard_size(gqa, mesh8) == 1
 
 
 # -- paged_span_fits per-shard accounting -------------------------------------
