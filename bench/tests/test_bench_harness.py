@@ -1,0 +1,123 @@
+"""The harness finds a cell's files by name, new ones included, prints the
+result line in the agreed shape, and refuses to run without a TPU."""
+
+import json
+import os
+import shutil
+import subprocess
+import sys
+from pathlib import Path
+
+import pytest
+
+from bench import run as R
+from bench.tests import tiny
+
+ROOT = Path(__file__).resolve().parents[2]
+NEW_READER = "def read(ctx):\n    return 42.0\n"
+
+
+@pytest.fixture(scope="module")
+def layout(tmp_path_factory):
+    root = tiny.write(tmp_path_factory.mktemp("tiny"),
+                      extra_metrics={"tiny_counter": NEW_READER})
+    return R.Layout(root)
+
+
+def test_files_found_by_name(layout):
+    files = layout.cell("tiny.sessions")
+    assert files["config"]["name"] == "tiny"
+    assert files["mix"]["name"] == "tiny_sessions"
+    assert files["limits"] == tiny.LIMITS
+    with pytest.raises(KeyError):
+        layout.cell("no.such_cell")
+
+
+def test_metric_added_as_a_file(layout):
+    # a reader of its own, and a group metric served by its base's reader
+    assert layout.reader("tiny_counter.backlog")(None) == 42.0
+    read = layout.reader("batch_occupancy.backlog")
+    ctx = R.TracedRun(trace=None, stretch_steps=0, stretch_work=[],
+                      peaks={}, host_step_ms=[], prefix_hit_tokens=0,
+                      prompt_tokens_admitted=0, batch_sum=6.0,
+                      batch_count=2, max_slots=4)
+    assert read(ctx) == 75.0
+    with pytest.raises(FileNotFoundError):
+        layout.reader("no_such_metric")
+
+
+def test_metrics_of_a_cell(layout):
+    e2e = [m["name"] for m in layout.metrics("tiny.backlog", "end_to_end")]
+    assert e2e == ["itl_p95_ms", "output_tok_s", "setup_s"]
+    e2e = [m["name"] for m in layout.metrics("tiny.sessions", "end_to_end")]
+    assert e2e == ["ttft_p90_ms", "itl_p95_ms", "output_tok_s", "setup_s"]
+    per = [m["name"] for m in layout.metrics("tiny.backlog", "per_layer")]
+    assert per == ["host_ms_per_step.backlog", "batch_occupancy.backlog",
+                   "prefix_hit_share.backlog", "tiny_counter.backlog"]
+
+
+def test_benchmark_json_metrics_have_readers():
+    layout = R.Layout(ROOT)
+    for m in layout.spec["per_layer"]:
+        assert callable(layout.reader(m["name"]))
+        for cell in m["workloads"]:
+            moves = {e["name"]: e for e in layout.spec["end_to_end"]}
+            e = moves[m["moves"]]
+            assert "workloads" not in e or cell in e["workloads"]
+    for w in layout.spec["workloads"]:
+        files = layout.cell(w["name"])
+        assert files["mix"]["max_len"] > 0
+        assert set(files["limits"]) == {"compare", "sample_tokens",
+                                        "min_tokens"}
+        assert files["limits"]["compare"]
+
+
+@pytest.mark.parametrize("trace", [0, 1])
+def test_result_line_shape(layout, trace):
+    args = R.parse(["--workload", "tiny.sessions", "--seed", "17",
+                    "--seconds", "1.5", "--trace", str(trace)])
+    out = R.run(args, layout, chip=False)
+    line = json.loads(json.dumps(out))
+    assert list(line)[:5] == ["correct", "attempted", "failed", "metrics",
+                              "device"]
+    assert list(line)[-1] == "check"
+    assert isinstance(line["correct"], bool)
+    assert line["attempted"] >= line["failed"] >= 0
+    dev = line["device"]
+    assert {"platform", "kind", "count", "memory_peak_bytes"} <= set(dev)
+    kind = "per_layer" if trace else "end_to_end"
+    names = {m["name"] for m in layout.metrics("tiny.sessions", kind)}
+    assert set(line["metrics"]) <= names
+    assert line["metrics"], "a run reports at least one metric"
+    for m in line["metrics"].values():
+        assert set(m) == {"value", "unit"}
+    if not trace:
+        assert set(line["metrics"]) == names
+    for c in line["check"].values():
+        assert "value" in c and ("max" in c or "min" in c)
+
+
+def _run_bench(cwd, env_extra=None):
+    env = dict(os.environ, JAX_PLATFORMS="cpu", **(env_extra or {}))
+    env.pop("PYTHONPATH", None)
+    return subprocess.run(
+        [sys.executable, "bench/run.py", "--workload",
+         "codeqwen7b.chat_backlog", "--seed", "1", "--seconds", "1",
+         "--trace", "0"], cwd=cwd, env=env, capture_output=True, text=True,
+        timeout=300)
+
+
+def test_refuses_without_a_tpu():
+    p = _run_bench(ROOT)
+    assert p.returncode != 0
+    assert p.stdout.strip() == ""
+    assert "needs a TPU" in p.stderr
+
+
+def test_refuses_with_only_the_benchmark_files(tmp_path):
+    shutil.copy(ROOT / "BENCHMARK.json", tmp_path)
+    shutil.copytree(ROOT / "bench", tmp_path / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    p = _run_bench(tmp_path)
+    assert p.returncode != 0
+    assert p.stdout.strip() == ""
