@@ -1,0 +1,9 @@
+"""Paged attention: device self time of the ``attention`` scope (RoPE,
+KV write, gather, scores, softmax, AV; profiler trace) against the
+roofline of its part of each step's count (bench/parts.py)."""
+
+from bench.parts import roofline_share
+
+
+def read(ctx):
+    return roofline_share(ctx, "attention")

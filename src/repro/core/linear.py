@@ -76,12 +76,18 @@ def linear_apply(
     The product is computed in ``x.dtype`` whatever the weights are stored
     in: weights are cast to the activation dtype (a no-op when they already
     match), so a bf16 residual stream stays bf16 over fp32 master weights
-    and the MXU runs at activation precision."""
+    and the MXU runs at activation precision.  Its operations carry the
+    ``linear`` scope (``serving/tracing.py`` lists the scopes)."""
     if "w" in params and isinstance(params["w"], dict):
         inner = dict(params["w"])
         if "b" in params:
             inner["b"] = params["b"]
         return linear_apply(inner, x, precision=precision, backend=backend)
+    with jax.named_scope("linear"):
+        return _linear(params, x, precision, backend)
+
+
+def _linear(params, x, precision, backend):
     dt = x.dtype
     if qn.is_quantized(params):
         if backend == "pallas":

@@ -32,15 +32,17 @@ def norm_init(d: int, kind: str) -> dict:
 
 
 def norm_apply(params: dict, x: jax.Array, kind: str, eps: float = 1e-6) -> jax.Array:
-    xf = x.astype(jnp.float32)
-    if kind == "rmsnorm":
-        var = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
-        out = xf * jax.lax.rsqrt(var + eps) * (1.0 + 0.0 + params["scale"])
-    else:
-        mean = jnp.mean(xf, axis=-1, keepdims=True)
-        var = jnp.var(xf, axis=-1, keepdims=True)
-        out = (xf - mean) * jax.lax.rsqrt(var + eps) * params["scale"] + params["bias"]
-    return out.astype(x.dtype)
+    with jax.named_scope("norm"):
+        xf = x.astype(jnp.float32)
+        if kind == "rmsnorm":
+            var = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+            out = xf * jax.lax.rsqrt(var + eps) * (1.0 + 0.0 + params["scale"])
+        else:
+            mean = jnp.mean(xf, axis=-1, keepdims=True)
+            var = jnp.var(xf, axis=-1, keepdims=True)
+            out = ((xf - mean) * jax.lax.rsqrt(var + eps) * params["scale"]
+                   + params["bias"])
+        return out.astype(x.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -203,90 +205,93 @@ def attention_apply(
     path — global positions below it sit in refcount-shared prefix pages
     and their writes are redirected to the sink (shared history is
     immutable; reads still gather through the page table).
-    Returns (out, updated_cache).
+    Returns (out, updated_cache).  Its operations carry the ``attention``
+    scope, its projections the nested ``linear`` one.
     """
-    B, S, d = x.shape
-    h, kv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
-    dtype = x.dtype
+    with jax.named_scope("attention"):
+        B, S, d = x.shape
+        h, kv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
+        dtype = x.dtype
 
-    kv_src = x if kv_input is None else kv_input
-    Skv = kv_src.shape[1]
-    if "wqkv" in params:
-        # fused projection: one weight visit computes q, k and v (exact
-        # concatenation of the separate outputs — see models/fuse.py)
-        assert kv_input is None, "fused QKV is self-attention only"
-        qd, kd = h * hd, kv * hd
-        qkv = linear_apply(params["wqkv"], x, backend=backend)
-        q = qkv[..., :qd].reshape(B, S, h, hd)
-        k = qkv[..., qd:qd + kd].reshape(B, Skv, kv, hd)
-        v = qkv[..., qd + kd:].reshape(B, Skv, kv, hd)
-    elif "wkv" in params:
-        q = linear_apply(params["wq"], x, backend=backend).reshape(B, S, h, hd)
-        kvh = linear_apply(params["wkv"], kv_src, backend=backend)
-        k = kvh[..., :kv * hd].reshape(B, Skv, kv, hd)
-        v = kvh[..., kv * hd:].reshape(B, Skv, kv, hd)
-    else:
-        q = linear_apply(params["wq"], x, backend=backend).reshape(B, S, h, hd)
-        k = linear_apply(params["wk"], kv_src, backend=backend).reshape(
-            B, Skv, kv, hd)
-        v = linear_apply(params["wv"], kv_src, backend=backend).reshape(
-            B, Skv, kv, hd)
-
-    if pos is None:
-        q_pos = jnp.arange(S)
-        k_pos = jnp.arange(Skv)
-    else:  # cached: per-row start position, S consecutive new tokens
-        q_pos = pos.reshape(B, 1) + jnp.arange(S)[None, :]
-        k_pos = q_pos
-    if not bidir and kv_input is None:
-        q = rope(q, q_pos, cfg.rope_theta)
-        k = rope(k, k_pos, cfg.rope_theta)
-    if cfg.qk_norm:
-        q = q / (jnp.linalg.norm(q, axis=-1, keepdims=True) + 1e-6)
-        k = k / (jnp.linalg.norm(k, axis=-1, keepdims=True) + 1e-6)
-
-    q = logical(q, "batch", "seq", "heads", "head_dim")
-    k = logical(k, "batch", "seq" if cache is None else "kv_seq", "kv_heads", "head_dim")
-    v = logical(v, "batch", "seq" if cache is None else "kv_seq", "kv_heads", "head_dim")
-
-    new_cache = None
-    if cache is not None and "k_pages" in cache:
-        out, new_cache = _paged_attend(
-            q, k, v, cache, page_table, q_pos, cfg, window, dtype,
-            span_len=span_len, write_start=write_start)
-    elif cache is not None:
-        # write the S new k/v rows at pos..pos+S-1 into the ring cache,
-        # attend each query over the cache under its own causal horizon
-        ck, cv = cache["k"], cache["v"]
-        T = ck.shape[1]
-        rows = jnp.arange(B)[:, None]
-        ck = ck.at[rows, q_pos].set(k)
-        cv = cv.at[rows, q_pos].set(v)
-        new_cache = {"k": ck, "v": cv}
-        kj = jnp.arange(T)[None, None, :]
-        valid = kj <= q_pos[..., None]  # (B,S,T)
-        if window is not None:
-            valid &= (q_pos[..., None] - kj) < window
-        mask = valid[:, None, None]  # (B,1,1,S,T)
-        out = _sdpa(q, ck, cv, mask, cfg.logit_softcap, dtype,
-                    fast_scores=cfg.fast_decode_scores)
-    elif (cfg.attn_chunk is not None and kv_input is None
-          and Skv > cfg.attn_chunk):
-        out = _sdpa_chunked(q, k, v, cfg.logit_softcap, dtype,
-                            cfg.attn_chunk, window, bidir)
-    else:
-        if bidir:
-            mask = jnp.ones((1, 1, 1, S, Skv), dtype=bool)
-        elif kv_input is not None:  # cross-attention: attend everything
-            mask = jnp.ones((1, 1, 1, S, Skv), dtype=bool)
+        kv_src = x if kv_input is None else kv_input
+        Skv = kv_src.shape[1]
+        if "wqkv" in params:
+            # fused projection: one weight visit computes q, k and v (exact
+            # concatenation of the separate outputs — see models/fuse.py)
+            assert kv_input is None, "fused QKV is self-attention only"
+            qd, kd = h * hd, kv * hd
+            qkv = linear_apply(params["wqkv"], x, backend=backend)
+            q = qkv[..., :qd].reshape(B, S, h, hd)
+            k = qkv[..., qd:qd + kd].reshape(B, Skv, kv, hd)
+            v = qkv[..., qd + kd:].reshape(B, Skv, kv, hd)
+        elif "wkv" in params:
+            q = linear_apply(params["wq"], x, backend=backend).reshape(B, S, h, hd)
+            kvh = linear_apply(params["wkv"], kv_src, backend=backend)
+            k = kvh[..., :kv * hd].reshape(B, Skv, kv, hd)
+            v = kvh[..., kv * hd:].reshape(B, Skv, kv, hd)
         else:
-            mask = causal_mask(S, Skv, 0, window)
-        out = _sdpa(q, k, v, mask, cfg.logit_softcap, dtype,
-                    fast_scores=cfg.fast_decode_scores)
+            q = linear_apply(params["wq"], x, backend=backend).reshape(B, S, h, hd)
+            k = linear_apply(params["wk"], kv_src, backend=backend).reshape(
+                B, Skv, kv, hd)
+            v = linear_apply(params["wv"], kv_src, backend=backend).reshape(
+                B, Skv, kv, hd)
 
-    out = out.reshape(B, S, h * hd)
-    out = linear_apply(params["wo"], out, backend=backend)
-    return logical(out, "batch", "seq", "embed"), new_cache
+        if pos is None:
+            q_pos = jnp.arange(S)
+            k_pos = jnp.arange(Skv)
+        else:  # cached: per-row start position, S consecutive new tokens
+            q_pos = pos.reshape(B, 1) + jnp.arange(S)[None, :]
+            k_pos = q_pos
+        if not bidir and kv_input is None:
+            q = rope(q, q_pos, cfg.rope_theta)
+            k = rope(k, k_pos, cfg.rope_theta)
+        if cfg.qk_norm:
+            q = q / (jnp.linalg.norm(q, axis=-1, keepdims=True) + 1e-6)
+            k = k / (jnp.linalg.norm(k, axis=-1, keepdims=True) + 1e-6)
+
+        q = logical(q, "batch", "seq", "heads", "head_dim")
+        kv_seq = "seq" if cache is None else "kv_seq"
+        k = logical(k, "batch", kv_seq, "kv_heads", "head_dim")
+        v = logical(v, "batch", kv_seq, "kv_heads", "head_dim")
+
+        new_cache = None
+        if cache is not None and "k_pages" in cache:
+            out, new_cache = _paged_attend(
+                q, k, v, cache, page_table, q_pos, cfg, window, dtype,
+                span_len=span_len, write_start=write_start)
+        elif cache is not None:
+            # write the S new k/v rows at pos..pos+S-1 into the ring cache,
+            # attend each query over the cache under its own causal horizon
+            ck, cv = cache["k"], cache["v"]
+            T = ck.shape[1]
+            rows = jnp.arange(B)[:, None]
+            ck = ck.at[rows, q_pos].set(k)
+            cv = cv.at[rows, q_pos].set(v)
+            new_cache = {"k": ck, "v": cv}
+            kj = jnp.arange(T)[None, None, :]
+            valid = kj <= q_pos[..., None]  # (B,S,T)
+            if window is not None:
+                valid &= (q_pos[..., None] - kj) < window
+            mask = valid[:, None, None]  # (B,1,1,S,T)
+            out = _sdpa(q, ck, cv, mask, cfg.logit_softcap, dtype,
+                        fast_scores=cfg.fast_decode_scores)
+        elif (cfg.attn_chunk is not None and kv_input is None
+              and Skv > cfg.attn_chunk):
+            out = _sdpa_chunked(q, k, v, cfg.logit_softcap, dtype,
+                                cfg.attn_chunk, window, bidir)
+        else:
+            if bidir:
+                mask = jnp.ones((1, 1, 1, S, Skv), dtype=bool)
+            elif kv_input is not None:  # cross-attention: attend everything
+                mask = jnp.ones((1, 1, 1, S, Skv), dtype=bool)
+            else:
+                mask = causal_mask(S, Skv, 0, window)
+            out = _sdpa(q, k, v, mask, cfg.logit_softcap, dtype,
+                        fast_scores=cfg.fast_decode_scores)
+
+        out = out.reshape(B, S, h * hd)
+        out = linear_apply(params["wo"], out, backend=backend)
+        return logical(out, "batch", "seq", "embed"), new_cache
 
 
 def attention_cache_init(cfg: ModelConfig, batch: int, max_len: int, dtype) -> dict:
@@ -631,21 +636,25 @@ def embedding_init(key, cfg: ModelConfig) -> dict:
 
 
 def embed(params: dict, tokens: jax.Array, cfg: ModelConfig, dtype) -> jax.Array:
-    x = params["table"][tokens].astype(dtype)  # gather rows, then cast
-    x = x * math.sqrt(cfg.d_model) if cfg.norm_type == "rmsnorm" else x
-    return logical(x, "batch", "seq", "embed")
+    with jax.named_scope("embed"):
+        x = params["table"][tokens].astype(dtype)  # gather rows, then cast
+        x = x * math.sqrt(cfg.d_model) if cfg.norm_type == "rmsnorm" else x
+        return logical(x, "batch", "seq", "embed")
 
 
 def unembed(params: dict, x: jax.Array, cfg: ModelConfig) -> jax.Array:
-    if cfg.tie_embeddings:
-        logits = jnp.einsum("bsd,vd->bsv", x, params["table"].astype(x.dtype))
-    else:
-        logits = jnp.einsum("bsd,dv->bsv", x, params["unembed"].astype(x.dtype))
-    logits = _softcap(logits.astype(jnp.float32), cfg.final_softcap)
-    if cfg.vocab_padded > cfg.vocab:  # mask padding slots (softmax-neutral)
-        valid = jnp.arange(cfg.vocab_padded) < cfg.vocab
-        logits = jnp.where(valid[None, None, :], logits, -1e30)
-    return logical(logits, "batch", "seq", "vocab")
+    with jax.named_scope("unembed"):
+        if cfg.tie_embeddings:
+            w = params["table"].astype(x.dtype)
+            logits = jnp.einsum("bsd,vd->bsv", x, w)
+        else:
+            w = params["unembed"].astype(x.dtype)
+            logits = jnp.einsum("bsd,dv->bsv", x, w)
+        logits = _softcap(logits.astype(jnp.float32), cfg.final_softcap)
+        if cfg.vocab_padded > cfg.vocab:  # mask padding (softmax-neutral)
+            valid = jnp.arange(cfg.vocab_padded) < cfg.vocab
+            logits = jnp.where(valid[None, None, :], logits, -1e30)
+        return logical(logits, "batch", "seq", "vocab")
 
 
 def cross_entropy(logits: jax.Array, labels: jax.Array,

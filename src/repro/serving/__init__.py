@@ -108,7 +108,11 @@ dispatch, because the engine's one-step harvest lag would otherwise
 antedate them.  ``ContinuousBatchingEngine(..., trace="out.json")``
 brackets each iteration's phases (plan / admit / dispatch / sync /
 harvest) with Chrome trace-event spans — ``engine.tracer.save()`` writes
-Perfetto-loadable JSON — and a ``Calibration`` pairs each step's
+Perfetto-loadable JSON; the same spans also land in an active
+``jax.profiler`` trace as host annotations, and the mixed step's layers
+carry the ``jax.named_scope`` names (``linear``, ``attention``,
+``unembed``, ``embed``, ``norm``, ``sample``) that ``tracing.py`` lists as
+the contract the benchmark reads — and a ``Calibration`` pairs each step's
 cost-model prediction (``sim_latency_ns``) with measured wall time,
 fitting the scale factor ``benchmarks/serve_throughput.py`` publishes in
 ``BENCH_serving.json``'s ``telemetry`` section.  ``metrics=False`` keeps
@@ -236,9 +240,11 @@ Module map:
   snapshot.py  — ``snapshot_engine`` / ``restore_engine`` and the on-disk
                  round trip (``save_snapshot`` / ``load_snapshot``) via
                  ``checkpoint/store.py``.
-  tracing.py   — ``ChromeTracer`` Chrome trace-event spans (Perfetto),
-                 the no-op ``NULL_TRACER``, and ``validate_trace`` (the
-                 machine-checkable "loads in Perfetto").
+  tracing.py   — ``ChromeTracer`` Chrome trace-event spans (Perfetto,
+                 and profiler annotations when enabled), the no-op
+                 ``NULL_TRACER``, ``validate_trace`` (the machine-checkable
+                 "loads in Perfetto"), and the contract of the mixed
+                 step's named scopes.
 
 The span-aware Pallas paged-gather attention kernel lives in
 ``kernels/paged.py`` (oracles: ``kernels/ref.py::paged_attention_span_ref``

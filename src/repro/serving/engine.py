@@ -160,15 +160,17 @@ def _mixed_step_body(params, pool, chunk_tok, tok_dev, use_dev, start, span,
     advance only on draws, so chunking never perturbs sampling.
     ``wstart`` (B,) is each row's copy-on-write fork point: positions below
     it live in shared prefix pages and are never written (mask-enforced in
-    the kernel-side page write, independent of host bookkeeping)."""
+    the kernel-side page write, independent of host bookkeeping).  The
+    draw carries the ``sample`` scope (``serving/tracing.py``)."""
     col0 = jnp.where(use_dev, tok_dev, chunk_tok[:, 0])
     tokens = chunk_tok.at[:, 0].set(col0)
     logits, pool = T.paged_mixed_step(params, tokens, start, span, pt, pool,
                                       cfg, write_start=wstart)
-    draw, carry = _split_rows(keys)
-    sampled = _sample_rows(logits, temp, draw)
-    tok_new = jnp.where(sample_mask, sampled, tok_dev)
-    keys_new = jnp.where(sample_mask[:, None], carry, keys)
+    with jax.named_scope("sample"):
+        draw, carry = _split_rows(keys)
+        sampled = _sample_rows(logits, temp, draw)
+        tok_new = jnp.where(sample_mask, sampled, tok_dev)
+        keys_new = jnp.where(sample_mask[:, None], carry, keys)
     return pool, sampled, tok_new, keys_new
 
 
@@ -857,10 +859,15 @@ class ContinuousBatchingEngine:
         """Grow page tables to cover every span, build the (slot, span)
         batch, and dispatch the jitted mixed step."""
         with self.tracer.span("dispatch", step=self.step_idx,
-                              spans=len(spans)):
-            self._dispatch_inner(spans)
+                              spans=len(spans)) as sp:
+            bucket, prefill_rows, decode_rows = self._dispatch_inner(spans)
+            sp.note(bucket=bucket, prefill_rows=prefill_rows,
+                    decode_rows=decode_rows)
 
-    def _dispatch_inner(self, spans: list[tuple[Sequence, int]]) -> None:
+    def _dispatch_inner(self, spans: list[tuple[Sequence, int]]
+                        ) -> tuple[int, int, int]:
+        """Returns the step's span bucket and its prefill and decode row
+        counts."""
         # injected dispatch failures fire HERE, before any host bookkeeping
         # (cursor advances, page draws) — the recovery path in _step_inner
         # assumes a failed dispatch mutated nothing
@@ -963,8 +970,9 @@ class ContinuousBatchingEngine:
 
         if self.metrics_enabled or self.tracer.enabled:
             # per-iteration batch composition + pool pressure.  stats() is a
-            # full pool scan, but pools are a few hundred pages at most and
-            # this runs once per step, off by default with metrics=False.
+            # full pool scan, once per step: thousands of pages on a chip
+            # (3,770 in the benchmark's one-v5e pool), off by default with
+            # metrics=False.
             ps = self.pool_host.stats()
             if self.metrics_enabled:
                 self._h_batch.observe(n_rows)
@@ -986,6 +994,7 @@ class ContinuousBatchingEngine:
             self._keys)
         self._pending.append({"sampled": sampled, "slots": harvest,
                               "step": self.step_idx})
+        return Sb, n_rows - n_dec, n_dec
 
     def _kernel_decision(self, span: int) -> str:
         """The kernel-vs-dense decision the traced mixed step took for this

@@ -9,6 +9,43 @@ thread track, which Perfetto nests by containment — plus optional instant
 ``save()``/``to_json()`` is the standard Trace Event Format object
 (``{"traceEvents": [...]}``) loadable at https://ui.perfetto.dev.
 
+Every enabled span also enters a ``jax.profiler.TraceAnnotation`` of the
+same name (``StepTraceAnnotation`` with ``step_num`` for ``step``), so
+when a ``jax.profiler`` trace is active the engine's phases land on a host
+line of its ``.xplane.pb``, on the clock of the device's operations.  The
+JSON events are unchanged by it.  ``dispatch`` carries the step's span
+``bucket`` and its ``prefill_rows`` / ``decode_rows`` in its args.
+
+Inside the jitted mixed step the layers carry ``jax.named_scope`` names,
+which reach each compiled operation's HLO ``op_name`` metadata and the
+profiler's view of that operation.  An operation belongs to the innermost
+of these scopes; the names are a contract the benchmark's trace reduction
+reads (``bench/scopes.py``):
+
+=============  ======================================  =====================
+scope          where                                   covers
+=============  ======================================  =====================
+``linear``     ``core/linear.py`` ``linear_apply``     every projection:
+                                                       Monarch einsum or
+                                                       Pallas, quantized,
+                                                       dense
+``attention``  ``models/layers.py``                    RoPE, the KV page
+               ``attention_apply``                     write, the gather or
+                                                       span kernel, scores,
+                                                       softmax, AV
+``unembed``    ``models/layers.py`` ``unembed``        vocabulary projection
+``embed``      ``models/layers.py`` ``embed``          token embedding
+``norm``       ``models/layers.py`` ``norm_apply``     RMSNorm / LayerNorm
+``sample``     ``serving/engine.py``                   the greedy or
+               ``_mixed_step_body``                    temperature draw and
+                                                       its token/key updates
+=============  ======================================  =====================
+
+Scopes are metadata only: they change no compiled operation.  So JAX's
+persistent compilation cache, whose key leaves metadata out, may hand back
+an executable compiled from the same program without them; a trace that
+must show them sets ``jax_compilation_cache_include_metadata_in_key``.
+
 When tracing is off the engine holds the module-level ``NULL_TRACER``:
 ``span()`` returns one reusable no-op context manager and the counter/
 instant hooks return immediately, so the instrumented hot path costs a
@@ -27,26 +64,42 @@ import json
 import time
 from typing import Optional
 
+import jax
+
 _VALID_PHASES = {"X", "B", "E", "i", "I", "C", "M"}
 
 
 class _Span:
-    """One timed section; appends a complete ("X") event on exit."""
+    """One timed section; appends a complete ("X") event on exit, and runs
+    inside a profiler annotation of the same name (a step annotation for
+    ``step``), so an active ``jax.profiler`` trace shows it on the host
+    line beside the device's operations."""
 
-    __slots__ = ("_tracer", "_name", "_args", "_t0")
+    __slots__ = ("_tracer", "_name", "_args", "_t0", "_annotation")
 
     def __init__(self, tracer: "ChromeTracer", name: str, args: dict):
         self._tracer = tracer
         self._name = name
         self._args = args
 
+    def note(self, **args) -> None:
+        """Add args known only once the section has run."""
+        self._args.update(args)
+
     def __enter__(self) -> "_Span":
+        if self._name == "step" and "step" in self._args:
+            self._annotation = jax.profiler.StepTraceAnnotation(
+                "step", step_num=self._args["step"])
+        else:
+            self._annotation = jax.profiler.TraceAnnotation(self._name)
+        self._annotation.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
-        tr = self._tracer
         t1 = time.perf_counter()
+        self._annotation.__exit__(exc_type, exc, tb)
+        tr = self._tracer
         ev = {"name": self._name, "ph": "X", "pid": tr.pid, "tid": tr.tid,
               "ts": (self._t0 - tr._epoch) * 1e6,
               "dur": (t1 - self._t0) * 1e6}
@@ -59,6 +112,9 @@ class _NullSpan:
     """Reusable no-op context manager (one shared instance)."""
 
     __slots__ = ()
+
+    def note(self, **args) -> None:
+        return None
 
     def __enter__(self):
         return self

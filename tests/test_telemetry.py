@@ -418,3 +418,117 @@ def test_telemetry_does_not_change_outputs(params):
         return [r.output_tokens for r in reqs]
 
     assert run(metrics=True, trace=True) == run(metrics=False)
+
+
+# ---------------------------------------------------------------------------
+# the profiler's view: named scopes in the mixed step, spans on its clock
+# ---------------------------------------------------------------------------
+
+SCOPES = ("linear", "attention", "unembed", "embed", "norm", "sample")
+
+
+def _op_scopes(hlo_text: str) -> list[tuple[str, str]]:
+    """(opcode, innermost program scope or "-") of every instruction of a
+    compiled module's text that carries an ``op_name``."""
+    import re
+
+    out = []
+    for m in re.finditer(r"= \S+ ([\w\-]+)\(.*?op_name=\"([^\"]+)\"",
+                         hlo_text):
+        path = m.group(2).split("/")
+        scope = next((p for p in reversed(path) if p in SCOPES), "-")
+        out.append((m.group(1), scope))
+    return out
+
+
+def test_mixed_step_ops_carry_layer_scopes():
+    """The compiled mixed step of a tiny Monarch model: every matmul is a
+    projection, attention or the unembedding; the KV page write and the
+    page gathers sit in attention; the greedy argmax in sample."""
+    import jax.numpy as jnp
+
+    from repro.core.linear import MonarchSpec
+    from repro.serving import engine as E
+
+    cfg = ModelConfig(name="t", d_model=64, n_layers=2, n_heads=4,
+                      n_kv_heads=2, d_ff=128, vocab=256, dtype="float32",
+                      monarch=MonarchSpec(enable=True, min_dim=16))
+    p = jax.eval_shape(lambda: T.init_params(jax.random.PRNGKey(0), cfg))
+    pool = jax.eval_shape(lambda: T.init_paged_pool(cfg, 9, 4))
+    B, S = 2, 4
+    i32 = lambda *s: jnp.zeros(s, jnp.int32)  # noqa: E731
+    args = (p, pool, i32(B, S), i32(B), jnp.zeros((B,), bool), i32(B),
+            jnp.ones((B,), jnp.int32), i32(B, 8), i32(B),
+            jnp.ones((B,), bool), jnp.zeros((B,), jnp.float32),
+            jnp.zeros((B, 2), jnp.uint32))
+    hlo = E._mixed_step_jit.lower(*args, cfg=cfg).compile().as_text()
+    ops = _op_scopes(hlo)
+    by_op: dict = {}
+    for op, scope in ops:
+        by_op.setdefault(op, set()).add(scope)
+    assert by_op["dot"] == {"linear", "attention", "unembed"}
+    assert by_op["scatter"] == {"attention"}
+    assert {"attention", "embed"} <= by_op["gather"]
+    assert "norm" in by_op["reduce"]
+    # argmax lowers to a reduce of (values, indices) pairs
+    import re
+
+    argmax = re.findall(r"= \(f32\[\d+\]\{0\}, s32\[\d+\]\{0\}\) "
+                        r"reduce\(.*op_name=\"([^\"]+)\"", hlo)
+    assert argmax and all("/sample/" in name for name in argmax)
+
+
+def _profiled_engine_spans(params, trace: bool, logdir) -> dict:
+    """Names of the engine's spans on the host planes of a profiler trace
+    taken around one short run."""
+    from jax.profiler import ProfileData
+
+    eng = ContinuousBatchingEngine(CFG, params, max_slots=2, page_size=4,
+                                   max_len=32, trace=trace)
+    eng.add_request(np.arange(6) % CFG.vocab,
+                    SamplingParams(max_new_tokens=3))
+    eng.run()                                       # compile outside
+    eng.add_request(np.arange(5) % CFG.vocab,
+                    SamplingParams(max_new_tokens=3))
+    jax.profiler.start_trace(str(logdir))
+    try:
+        eng.run()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = logdir.glob("**/*.xplane.pb")
+    data = ProfileData.from_file(str(path))
+    names = ("step", "plan", "admit", "dispatch", "sync", "harvest")
+    found: dict = {}
+    for plane in data.planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name in names:
+                    found.setdefault(e.name, []).append(
+                        dict((k, str(v)) for k, v in e.stats))
+    return found
+
+
+@pytest.mark.parametrize("trace", [True, False])
+def test_engine_spans_land_in_profiler_trace(params, tmp_path, trace):
+    found = _profiled_engine_spans(params, trace, tmp_path)
+    if not trace:
+        assert found == {}
+        return
+    assert {"step", "plan", "dispatch", "sync", "harvest"} <= set(found)
+    # the step span is a step annotation, numbered like the JSON event
+    assert all("step_num" in s for s in found["step"])
+    assert len(found["dispatch"]) == len(found["harvest"])
+
+
+def test_dispatch_span_carries_step_composition(params):
+    eng, _ = _run_contended(params, trace=True)
+    disp = [e["args"] for e in eng.tracer.events
+            if e["ph"] == "X" and e["name"] == "dispatch"]
+    assert len(disp) == eng.stats["mixed_steps"]
+    for a in disp:
+        assert a["bucket"] & (a["bucket"] - 1) == 0        # a power of two
+        assert 0 < a["prefill_rows"] + a["decode_rows"] <= a["spans"]
+    assert sum(a["decode_rows"] for a in disp) == eng.stats["decode_tokens"]
+    assert any(a["prefill_rows"] and a["decode_rows"] for a in disp)
