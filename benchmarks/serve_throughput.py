@@ -215,7 +215,8 @@ def _instrumented(params, prompts, gen, *, max_len, max_slots, chunk=None,
         kw["chunk_size"] = chunk
     if n_pages is not None:
         kw["n_pages"] = n_pages
-    if warm:  # compile every span bucket this config will hit, untimed
+    if warm:  # compile every step variant (row bucket) untimed: the
+        # engine compiles them all on its first mixed step
         _instrumented(params, prompts,
                       GenerationConfig(max_new_tokens=2,
                                        temperature=gen.temperature),
@@ -235,7 +236,6 @@ def _instrumented(params, prompts, gen, *, max_len, max_slots, chunk=None,
         submit(i)
     pending = list(range(head, len(prompts)))
     decode_ms, mixed_ms = [], []
-    seen_buckets: set[int] = set()
     t_all = time.perf_counter()
     step = 0
     while eng.has_work() or pending:
@@ -248,13 +248,6 @@ def _instrumented(params, prompts, gen, *, max_len, max_slots, chunk=None,
         jax.block_until_ready(eng._tok)
         dt = (time.perf_counter() - t0) * 1e3
         step += 1
-        bucket = getattr(eng, "last_span_bucket", 0)
-        if bucket not in seen_buckets:
-            # first step on a fresh span bucket pays its jit compile (the
-            # warm pass covers the common buckets, but preemption/stall
-            # shrinkage can mint new ones) — keep it out of the percentiles
-            seen_buckets.add(bucket)
-            continue
         if eng.stats["decode_tokens"] > d0:
             # a step a decoding request waited on; mixed == prefill rode along
             (mixed_ms if eng.stats["prefill_tokens"] > p0

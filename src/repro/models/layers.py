@@ -10,7 +10,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Optional
+from typing import Any, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -180,9 +180,7 @@ def attention_apply(
     window=None,
     cache: Optional[dict] = None,
     pos: Optional[jax.Array] = None,
-    page_table: Optional[jax.Array] = None,
-    span_len: Optional[jax.Array] = None,
-    write_start: Optional[jax.Array] = None,
+    groups: Optional[tuple["SpanRows", ...]] = None,
     kv_input: Optional[jax.Array] = None,
     bidir: bool = False,
     backend: str = "einsum",
@@ -192,19 +190,14 @@ def attention_apply(
     ``window``: None for full attention, or an int / traced scalar for a
     sliding window (traced per-layer values let local/global alternation
     share one scanned stack).
-    ``cache``: either the contiguous ring cache {"k": (B,T,KV,hd), "v": ...}
-    or a paged cache {"k_pages": (P,page,KV,hd), "v_pages": ...} addressed
-    through ``page_table`` (B, max_pages).  Both accept S >= 1 new tokens per
-    row (S > 1 is the chunked prefill / mixed-step path), written at
-    positions ``pos[b] + arange(S)``.
-    ``span_len``: (B,) valid new tokens per row of the paged path — rows may
-    carry spans shorter than S (the mixed decode + prefill-chunk batch);
-    positions at or beyond ``span_len[b]`` write to the sink page instead of
-    the sequence's tables.  None means every row's span is the full S.
-    ``write_start``: (B,) copy-on-write fork point per row of the paged
-    path — global positions below it sit in refcount-shared prefix pages
-    and their writes are redirected to the sink (shared history is
-    immutable; reads still gather through the page table).
+    ``cache``: either the contiguous ring cache {"k": (B,T,KV,hd), "v": ...},
+    which takes S >= 1 new tokens per row written at positions
+    ``pos[b] + arange(S)``, or a paged cache {"k_pages": (P,page,KV,hd),
+    "v_pages": ...}, which takes the packed token set of a paged step:
+    ``groups`` (``SpanRows``, in order) lay the B x S positions of ``x``
+    out as consecutive row groups of their own (rows, span) shapes.  The
+    projections run once over the packed set; each group writes its K/V
+    through its own page tables and attends on its own (``_paged_attend``).
     Returns (out, updated_cache).  Its operations carry the ``attention``
     scope, its projections the nested ``linear`` one.
     """
@@ -236,7 +229,11 @@ def attention_apply(
             v = linear_apply(params["wv"], kv_src, backend=backend).reshape(
                 B, Skv, kv, hd)
 
-        if pos is None:
+        if groups is not None:  # packed paged step: each group's positions
+            q_pos = jnp.concatenate(
+                [g.pos.reshape(-1) for g in groups]).reshape(B, S)
+            k_pos = q_pos
+        elif pos is None:
             q_pos = jnp.arange(S)
             k_pos = jnp.arange(Skv)
         else:  # cached: per-row start position, S consecutive new tokens
@@ -256,9 +253,8 @@ def attention_apply(
 
         new_cache = None
         if cache is not None and "k_pages" in cache:
-            out, new_cache = _paged_attend(
-                q, k, v, cache, page_table, q_pos, cfg, window, dtype,
-                span_len=span_len, write_start=write_start)
+            out, new_cache = _paged_attend_groups(q, k, v, cache, groups,
+                                                  cfg, window, dtype)
         elif cache is not None:
             # write the S new k/v rows at pos..pos+S-1 into the ring cache,
             # attend each query over the cache under its own causal horizon
@@ -336,6 +332,52 @@ def paged_cache_init(cfg: ModelConfig, n_pages: int, page_size: int, dtype,
         cache["k_scales"] = jnp.zeros((n_pages, kv), jnp.float32)
         cache["v_scales"] = jnp.zeros((n_pages, kv), jnp.float32)
     return cache
+
+
+class SpanRows(NamedTuple):
+    """One row group of a packed paged step: ``tokens`` (rows, S), row
+    ``b``'s token ``i`` at global position ``start[b] + i`` and real iff
+    ``i < span_len[b]`` (positions at or past it are padding and write to
+    the sink page; 0 makes the row inert); ``page_table`` (rows,
+    max_pages) physical page ids; ``write_start`` optional (rows,)
+    copy-on-write fork points — positions below one sit in refcount-shared
+    prefix pages and their writes are redirected to the sink (shared
+    history is immutable; reads still gather through the page table)."""
+
+    tokens: jax.Array
+    start: jax.Array
+    span_len: jax.Array
+    page_table: jax.Array
+    write_start: Optional[jax.Array] = None
+
+    @property
+    def pos(self) -> jax.Array:
+        """(rows, S) global position of every token."""
+        return self.start[:, None] + jnp.arange(self.tokens.shape[1],
+                                                dtype=jnp.int32)
+
+
+def _paged_attend_groups(q, k, v, cache, groups, cfg: ModelConfig, window,
+                         dtype):
+    """Split the packed q/k/v ((B, S, ...) holding the groups' positions in
+    order) by static slices, and write and attend each group through its
+    own page tables.  A slot belongs to one group and copy-on-write pages
+    are never written, so one group's writes never land where another
+    group reads."""
+    B, S = q.shape[:2]
+    q, k, v = (a.reshape(B * S, *a.shape[2:]) for a in (q, k, v))
+    outs, off = [], 0
+    for g in groups:
+        rows, span = g.tokens.shape
+        part = lambda a: a[off:off + rows * span].reshape(  # noqa: E731
+            rows, span, *a.shape[1:])
+        out, cache = _paged_attend(
+            part(q), part(k), part(v), cache, g.page_table, g.pos, cfg,
+            window, dtype, span_len=g.span_len, write_start=g.write_start)
+        outs.append(out.reshape(rows * span, *out.shape[2:]))
+        off += rows * span
+    assert off == B * S, (off, B, S)
+    return jnp.concatenate(outs).reshape(B, S, *outs[0].shape[1:]), cache
 
 
 def _paged_attend(q, k, v, cache, page_table, q_pos, cfg: ModelConfig,
@@ -670,7 +712,7 @@ def cross_entropy(logits: jax.Array, labels: jax.Array,
 __all__ = [
     "norm_init", "norm_apply", "rope",
     "attention_init", "attention_apply", "attention_cache_init",
-    "paged_cache_init", "causal_mask",
+    "paged_cache_init", "SpanRows", "causal_mask",
     "ffn_init", "ffn_apply", "moe_init", "moe_apply",
     "embedding_init", "embed", "unembed", "cross_entropy",
 ]

@@ -71,9 +71,7 @@ def attn_block_apply(
     window=None,
     cache: Optional[dict] = None,
     pos=None,
-    page_table=None,
-    span_len=None,
-    write_start=None,
+    groups=None,
     enc_out=None,
     bidir: bool = False,
 ) -> tuple[jax.Array, Optional[dict], jax.Array]:
@@ -81,8 +79,7 @@ def attn_block_apply(
     h = L.norm_apply(p["ln1"], x, cfg.norm_type)
     a, new_attn_cache = L.attention_apply(
         p["attn"], h, cfg, window=window, cache=cache["attn"] if cache else None,
-        pos=pos, page_table=page_table, span_len=span_len,
-        write_start=write_start, bidir=bidir,
+        pos=pos, groups=groups, bidir=bidir,
         backend=cfg.monarch.backend,
     )
     if cfg.sandwich_norm:
@@ -175,9 +172,7 @@ def decoder_stack_apply(
     *,
     cache: Optional[dict] = None,
     pos=None,
-    page_table=None,
-    span_len=None,
-    write_start=None,
+    groups=None,
     enc_out=None,
     bidir: bool = False,
     train: bool = True,
@@ -200,9 +195,8 @@ def decoder_stack_apply(
         def body(h, pl):
             p, win, c = pl
             h, nc, lb = attn_block_apply(
-                p, h, cfg, window=win, cache=c, pos=pos,
-                page_table=page_table, span_len=span_len,
-                write_start=write_start, enc_out=enc_out)
+                p, h, cfg, window=win, cache=c, pos=pos, groups=groups,
+                enc_out=enc_out)
             return h, (nc, lb)
         x, (new_caches, lbs) = jax.lax.scan(
             body, x, (params["layers"], windows, cache["layers"]))
@@ -460,40 +454,57 @@ def cow_copy_pages(pool: dict, src: jax.Array, dst: jax.Array) -> dict:
 
 def paged_mixed_step(params, tokens: jax.Array, start: jax.Array,
                      span_len: jax.Array, page_table: jax.Array, pool: dict,
-                     cfg: ModelConfig, write_start: jax.Array = None):
-    """ONE unified engine iteration: every row of the slot batch contributes
-    a variable-length token span — a prefill chunk, the tail of a chunked
-    prompt, or a single decode token.
+                     cfg: ModelConfig, write_start: jax.Array = None,
+                     decode: Optional[L.SpanRows] = None):
+    """ONE unified engine iteration: each row contributes a variable-length
+    token span — a prefill chunk, the tail of a chunked prompt, or a single
+    decode token — laid out as up to two row groups packed into one token
+    set.
 
-    tokens: (B, S) right-padded spans; row ``b``'s token ``i`` sits at
-    global position ``start[b] + i`` and is real iff ``i < span_len[b]``.
-    Real positions write k/v through ``page_table`` into the shared pool;
-    padding positions are redirected to the sink page (they can never touch
-    a live page — with incremental allocation the table may not even cover
-    them).  ``write_start`` (B,), when given, is each row's copy-on-write
-    fork point: positions below it live in refcount-shared prefix pages and
-    are additionally redirected to the sink — span writes are provably
-    confined to pages the row exclusively owns, whatever the host hands in.
-    (Reads are unaffected: attention gathers shared pages through the page
-    table like any other.)  Attention is causal within the span and over
-    all previously written positions.  A span of 0 makes the row fully
-    inert (pool untouched, logits garbage — the engine only samples rows
-    whose span reaches the end of their known tokens).
+    The span group: tokens (R, S) right-padded spans; row ``b``'s token
+    ``i`` sits at global position ``start[b] + i`` and is real iff
+    ``i < span_len[b]``.  ``decode``, when given, is a second group of
+    one position per row (tokens (B, 1)), which the step carries ahead of
+    the span group.  Norms, linears and the FFN run once over the B + R x S
+    packed positions; attention splits them back into the groups.
 
-    Returns (logits at each row's last real span position, updated pool).
-    Replaces the separate ``paged_prefill`` / ``paged_decode_step`` pair:
-    prefill is span == prompt chunk, decode is span == 1.
+    Real positions write k/v through their group's ``page_table`` into the
+    shared pool; padding positions are redirected to the sink page (they
+    can never touch a live page — with incremental allocation the table
+    may not even cover them).  ``write_start`` (rows,), when given, is each
+    row's copy-on-write fork point: positions below it live in
+    refcount-shared prefix pages and are additionally redirected to the
+    sink — span writes are provably confined to pages the row exclusively
+    owns, whatever the host hands in.  (Reads are unaffected: attention
+    gathers shared pages through the page table like any other.)  Attention
+    is causal within the span and over all previously written positions.
+    A span of 0 makes the row fully inert (pool untouched, logits garbage —
+    the engine only samples rows whose span reaches the end of their known
+    tokens).  A group with no rows is left out.
+
+    Returns (logits at each row's last real position — the decode group's
+    rows first, then the span group's — and the updated pool).  Prefill is
+    span == prompt chunk, decode is span == 1.
     """
-    B, S = tokens.shape
     dtype = _dtype(cfg)
-    x = L.embed(params["embedding"], tokens, cfg, dtype)
+    span = L.SpanRows(tokens, start, span_len, page_table, write_start)
+    groups = tuple(g for g in (decode, span)
+                   if g is not None and g.tokens.size)
+    packed = jnp.concatenate([g.tokens.reshape(-1) for g in groups])[None]
+    x = L.embed(params["embedding"], packed, cfg, dtype)  # (1, N, d)
     x, new_pool, _ = decoder_stack_apply(
-        params["decoder"], x, cfg, cache=pool, pos=start,
-        page_table=page_table, span_len=span_len, write_start=write_start,
-        train=False)
+        params["decoder"], x, cfg, cache=pool, groups=groups, train=False)
     x = L.norm_apply(params["ln_f"], x, cfg.norm_type)
-    idx = (jnp.maximum(span_len, 1) - 1)[:, None, None]
-    xl = jnp.take_along_axis(x, idx, axis=1)  # (B,1,d): last real position
+    last, off = [], 0
+    for g in groups:
+        n, s = g.tokens.shape
+        xg = x[0, off:off + n * s].reshape(n, s, -1)
+        if s > 1:   # last real position of each row
+            idx = (jnp.maximum(g.span_len, 1) - 1)[:, None, None]
+            xg = jnp.take_along_axis(xg, idx, axis=1)
+        last.append(xg[:, :1])
+        off += n * s
+    xl = jnp.concatenate(last)  # (rows, 1, d)
     logits = L.unembed(params["embedding"], xl, cfg)
     # under a tensor-parallel trace the unembed leaves logits split on the
     # vocab axis; constrain them so sampling sees the full row (no-op when

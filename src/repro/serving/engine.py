@@ -73,7 +73,7 @@ under token/page/latency budgets priced by the cost model.
     split on the KV-head axis (see ``serving/device_kv.py`` for the
     ownership contract).  Scheduling, preemption, prefix sharing and COW
     stay host-global (logical pages); only the bytes behind each page are
-    per-shard.  The mixed step still compiles ONCE (per span bucket) —
+    per-shard.  The mixed step still compiles ONCE (per row bucket) —
     ``_mixed_step_tp_jit`` bakes the mesh in as a static arg and GSPMD
     partitions the single forward.  ``mesh=None`` is byte-identical to
     the single-device engine.
@@ -96,6 +96,7 @@ import numpy as np
 
 from repro.models import transformer as T
 from repro.models.config import ModelConfig
+from repro.models.layers import SpanRows
 from repro.serving.faults import DispatchFailure
 from repro.serving.kv_pool import PagedKVPool, PoolOOM, SINK_PAGE
 from repro.serving.metrics import (Calibration, EngineStats,
@@ -143,35 +144,72 @@ def _bucket(n: int, lo: int = 1) -> int:
 
 # Module-level jits with the (frozen, hashable) ModelConfig as a static arg:
 # every engine instance of the same config shares one compiled step, so
-# constructing an engine never retraces.  The mixed step recompiles only per
-# span bucket (power-of-two padded max span), not per batch composition.
+# constructing an engine never retraces.  The mixed step compiles once per
+# row bucket of its span group (none, then powers of two up to the slot
+# count), not per batch composition.
 
 
 def _mixed_step_body(params, pool, chunk_tok, tok_dev, use_dev, start, span,
                      pt, wstart, sample_mask, temp, keys, cfg):
-    """ONE unified engine iteration over the slot batch.
+    """ONE unified engine iteration over the slot batch, as two row groups.
 
-    ``chunk_tok`` (B, S) carries host-known span tokens (prefill chunks);
-    rows flagged ``use_dev`` are decodes whose single input token is the
-    previous step's on-device sample (``tok_dev``), so the dispatch chain
-    never waits on a host readback.  Rows whose span reaches the end of
-    their known tokens (``sample_mask``) draw a token; everyone else keeps
-    their device token and PRNG stream untouched — per-request streams
-    advance only on draws, so chunking never perturbs sampling.
-    ``wstart`` (B,) is each row's copy-on-write fork point: positions below
-    it live in shared prefix pages and are never written (mask-enforced in
-    the kernel-side page write, independent of host bookkeeping).  The
-    draw carries the ``sample`` scope (``serving/tracing.py``)."""
-    col0 = jnp.where(use_dev, tok_dev, chunk_tok[:, 0])
-    tokens = chunk_tok.at[:, 0].set(col0)
-    logits, pool = T.paged_mixed_step(params, tokens, start, span, pt, pool,
-                                      cfg, write_start=wstart)
+    Per slot (B,): ``tok_dev`` the previous step's on-device sample,
+    ``use_dev`` whether the slot decodes this step, ``start``/``span`` its
+    span's first position and length, ``pt`` its page table, ``wstart`` its
+    copy-on-write fork point (positions below it live in shared prefix
+    pages and are never written — mask-enforced in the page write,
+    independent of host bookkeeping), ``sample_mask`` whether it draws,
+    ``temp``/``keys`` its temperature and PRNG stream.
+
+    * Decode group, (B, 1): every slot has one position, fed by
+      ``tok_dev`` so the dispatch chain never waits on a host readback;
+      slots not decoding this step are inert (span 0, sink page table).
+    * Span group, ``chunk_tok`` (R, S): the prefill chunks.  Row i is the
+      i-th slot, in ascending slot order, with ``~use_dev & (span > 0)``;
+      rows past the last such slot are padding (inert).  R and S are the
+      caller's buckets; R = B is the worst case.
+
+    Rows whose span reaches the end of their known tokens (``sample_mask``)
+    draw a token; everyone else keeps their device token and PRNG stream
+    untouched — per-request streams advance only on draws, so chunking
+    never perturbs sampling.  The draw carries the ``sample`` scope
+    (``serving/tracing.py``).  Returns (pool, sampled (B,), tok_new,
+    keys_new)."""
+    logits, pool = _slot_logits(params, pool, chunk_tok, tok_dev, use_dev,
+                                start, span, pt, wstart, cfg)
     with jax.named_scope("sample"):
         draw, carry = _split_rows(keys)
         sampled = _sample_rows(logits, temp, draw)
         tok_new = jnp.where(sample_mask, sampled, tok_dev)
         keys_new = jnp.where(sample_mask[:, None], carry, keys)
     return pool, sampled, tok_new, keys_new
+
+
+def _slot_logits(params, pool, chunk_tok, tok_dev, use_dev, start, span, pt,
+                 wstart, cfg):
+    """The forward of ``_mixed_step_body``: (each slot's logits at its last
+    real position (B, V), updated pool)."""
+    B, R = tok_dev.shape[0], chunk_tok.shape[0]
+    in_span = ~use_dev & (span > 0)
+    rank = jnp.cumsum(in_span.astype(jnp.int32)) - 1  # a span slot's row
+    # slot of span row i: the slots whose rank is below i precede it (a
+    # count, not jnp.nonzero, whose scatter would sit outside any scope)
+    slot = jnp.sum(rank[None, :] < jnp.arange(R)[:, None], axis=1)
+
+    def rows(a, fill):
+        return jnp.take(a, slot, axis=0, mode="fill", fill_value=fill)
+
+    decode = SpanRows(tok_dev[:, None], start, jnp.where(use_dev, span, 0),
+                      jnp.where(use_dev[:, None], pt, SINK_PAGE), wstart)
+    logits, pool = T.paged_mixed_step(
+        params, chunk_tok, rows(start, 0), rows(span, 0),
+        rows(pt, SINK_PAGE), pool, cfg, write_start=rows(wstart, 0),
+        decode=decode)
+    if not R:
+        return logits, pool
+    # a slot's logits: its decode row, or B + its span row
+    row = jnp.where(in_span, B + jnp.clip(rank, 0, R - 1), jnp.arange(B))
+    return logits[row], pool
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",), donate_argnums=(1,))
@@ -358,6 +396,10 @@ class ContinuousBatchingEngine:
 
         S, MP = max_slots, self.max_pages_per_seq
         self.max_slots = S
+        # the span group's fixed width: no chunk is longer than chunk_size,
+        # and none longer than max_len
+        self.span_bucket = _bucket(min(sc.chunk_size, max_len))
+        self._rows_warm = False   # every row bucket compiled (first mixed step)
         self._tok = jnp.zeros((S,), jnp.int32)
         self._temp = jnp.zeros((S,), jnp.float32)
         self._pt = jnp.full((S, MP), SINK_PAGE, jnp.int32)
@@ -860,29 +902,47 @@ class ContinuousBatchingEngine:
         batch, and dispatch the jitted mixed step."""
         with self.tracer.span("dispatch", step=self.step_idx,
                               spans=len(spans)) as sp:
-            bucket, prefill_rows, decode_rows = self._dispatch_inner(spans)
-            sp.note(bucket=bucket, prefill_rows=prefill_rows,
-                    decode_rows=decode_rows)
+            sp.note(**self._dispatch_inner(spans))
 
-    def _dispatch_inner(self, spans: list[tuple[Sequence, int]]
-                        ) -> tuple[int, int, int]:
-        """Returns the step's span bucket and its prefill and decode row
-        counts."""
+    def _row_bucket(self, n: int) -> int:
+        """Rows of the span group for ``n`` prefill rows: 0, else the
+        power-of-two bucket of ``n``, at most the slot count."""
+        return min(_bucket(n), self.max_slots) if n else 0
+
+    def _warm_row_buckets(self) -> None:
+        """Compile (or load) the mixed step for every row bucket before
+        any is needed, so none compiles mid-run: one inert dispatch per
+        bucket — every span 0 and every page table the sink, so live pages,
+        the slot tokens and the PRNG streams come out as they went in."""
+        self._rows_warm = True
+        B = self.max_slots
+        zeros = jnp.zeros((B,), jnp.int32)
+        no = jnp.zeros((B,), bool)
+        pt = jnp.full((B, self.max_pages_per_seq), SINK_PAGE, jnp.int32)
+        for R in sorted({self._row_bucket(n) for n in range(B + 1)}):
+            self.pool = self._mixed(
+                self.params, self.pool,
+                jnp.zeros((R, self.span_bucket), jnp.int32), self._tok, no,
+                zeros, zeros, pt, zeros, no, self._temp, self._keys)[0]
+
+    def _dispatch_inner(self, spans: list[tuple[Sequence, int]]) -> dict:
+        """Returns the step's composition, the ``dispatch`` span's args:
+        ``bucket`` (the span group's S, or 1 for a decode-only step),
+        ``rows`` (the span group's R), ``positions`` (B + R x S, what the
+        step computes), ``prefill_rows`` and ``decode_rows``."""
         # injected dispatch failures fire HERE, before any host bookkeeping
         # (cursor advances, page draws) — the recovery path in _step_inner
         # assumes a failed dispatch mutated nothing
         if self.faults is not None:
             self.faults.on_dispatch(self)
-        B = self.max_slots
-        Sb = _bucket(max(n for _, n in spans))
-        self.last_span_bucket = Sb  # instrumentation: which jit variant ran
-        chunk_tok = np.zeros((B, Sb), np.int32)
+        B, Sb = self.max_slots, self.span_bucket
         start = np.zeros((B,), np.int32)
         span = np.zeros((B,), np.int32)          # 0 = inert row (sink writes)
         use_dev = np.zeros((B,), bool)
         sample = np.zeros((B,), bool)
         harvest: list[tuple[int, Sequence]] = []
-        n_dec, dec_ctx, prefill_toks, n_rows = 0, 0, 0, 0
+        chunks: list[tuple[int, list[int]]] = []     # (slot, prefill tokens)
+        n_dec, dec_ctx, prefill_toks = 0, 0, 0
 
         for seq, n in spans:
             req = seq.request
@@ -917,8 +977,7 @@ class ContinuousBatchingEngine:
                     self.dispatch_log.append(
                         (self.step_idx, req.req_id, "decode", 1))
             else:                                    # prefill chunk
-                toks = req.known_tokens[nc:nc + n]
-                chunk_tok[s, :n] = toks
+                chunks.append((s, req.known_tokens[nc:nc + n]))
                 reaches_end = nc + n >= seq.prefill_target
                 sample[s] = reaches_end
                 prefill_toks += n
@@ -937,9 +996,18 @@ class ContinuousBatchingEngine:
                                                  req.known_tokens, nc + n)
             req.num_computed_tokens = nc + n
             self.pool_host.advance(req.req_id, n)
-            n_rows += 1
             if sample[s]:
                 harvest.append((s, seq))
+
+        # the span group: row i is the i-th prefill slot in slot order
+        R = self._row_bucket(len(chunks))
+        chunk_tok = np.zeros((R, Sb), np.int32)
+        for i, (_, toks) in enumerate(sorted(chunks, key=lambda c: c[0])):
+            chunk_tok[i, :len(toks)] = toks
+        bucket = Sb if R else 1
+        positions = B + R * Sb
+        self.stats["step_positions"] += positions
+        n_rows = n_dec + len(chunks)
 
         if self._pt_dirty:
             rows = np.full((len(self._pt_dirty), self.max_pages_per_seq),
@@ -958,10 +1026,11 @@ class ContinuousBatchingEngine:
         self.stats["mixed_steps"] += 1
 
         # kernel-dispatch observability: re-derive the SAME cached decision
-        # the traced step took for this span bucket (kernels.ops holds the
-        # one decision function), so the tp>1 kernel win — and any silent
-        # VMEM-spill regression when a bucket grows — shows up in stats
-        decision = self._kernel_decision(Sb)
+        # the traced step took for the span group's width (kernels.ops
+        # holds the one decision function), so the tp>1 kernel win — and
+        # any silent VMEM-spill regression when a bucket grows — shows up
+        # in stats
+        decision = self._kernel_decision(bucket)
         if decision == "kernel":
             self.stats["kernel_dispatches"] += 1
         else:
@@ -987,6 +1056,8 @@ class ContinuousBatchingEngine:
                     "pool_pages", free=ps.free_pages, shared=ps.shared_pages,
                     cached=ps.cached_pages)
 
+        if R and not self._rows_warm:
+            self._warm_row_buckets()
         (self.pool, sampled, self._tok, self._keys) = self._mixed(
             self.params, self.pool, jnp.asarray(chunk_tok), self._tok,
             jnp.asarray(use_dev), jnp.asarray(start), jnp.asarray(span),
@@ -994,11 +1065,12 @@ class ContinuousBatchingEngine:
             self._keys)
         self._pending.append({"sampled": sampled, "slots": harvest,
                               "step": self.step_idx})
-        return Sb, n_rows - n_dec, n_dec
+        return dict(bucket=bucket, rows=R, positions=positions,
+                    prefill_rows=len(chunks), decode_rows=n_dec)
 
     def _kernel_decision(self, span: int) -> str:
         """The kernel-vs-dense decision the traced mixed step took for this
-        span bucket — ``kernels.ops.paged_dispatch`` with exactly the
+        span width — ``kernels.ops.paged_dispatch`` with exactly the
         arguments ``models.layers._paged_attend`` derives from the traced
         shapes, so the counters can never drift from the compiled path.
         (Both calls hit the same ``lru_cache`` entry; this is a dict lookup

@@ -270,6 +270,9 @@ class MetricsRegistry:
 
 ENGINE_COUNTER_KEYS = (
     "mixed_steps", "decode_tokens", "prefill_tokens", "tokens_out",
+    # positions the mixed steps computed, padding included (B + R x S a
+    # step): 1 - (decode + prefill tokens) / step_positions is padding
+    "step_positions",
     "preemptions", "prefix_hit_tokens", "cow_forks",
     "sim_latency_ns", "sim_energy_nj",
     # fault-tolerance lifecycle counters (PR 7): explicit cancels, deadline

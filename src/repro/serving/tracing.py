@@ -13,8 +13,18 @@ Every enabled span also enters a ``jax.profiler.TraceAnnotation`` of the
 same name (``StepTraceAnnotation`` with ``step_num`` for ``step``), so
 when a ``jax.profiler`` trace is active the engine's phases land on a host
 line of its ``.xplane.pb``, on the clock of the device's operations.  The
-JSON events are unchanged by it.  ``dispatch`` carries the step's span
-``bucket`` and its ``prefill_rows`` / ``decode_rows`` in its args.
+JSON events are unchanged by it.  ``dispatch`` carries the step's
+composition in its args, as the mixed step lays it out (a decode group of
+one position per slot, and a span group of ``rows`` prefill rows each
+``bucket`` positions wide; ``serving/engine.py`` ``_mixed_step_body``):
+
+* ``bucket``: the span group's width S, a power of two; 1 for a
+  decode-only step;
+* ``rows``: the span group's row count R, the power-of-two bucket of
+  ``prefill_rows`` (at most the slot count), 0 for a decode-only step;
+* ``positions``: what the step computes, slots + R x S, padding included
+  (the engine sums it in ``stats["step_positions"]``);
+* ``prefill_rows`` / ``decode_rows``: the rows that carry a real span.
 
 Inside the jitted mixed step the layers carry ``jax.named_scope`` names,
 which reach each compiled operation's HLO ``op_name`` metadata and the

@@ -7,6 +7,8 @@ attention kernel vs its oracle, and token-identical equivalence between the
 unified mixed-step engine and the single-request path — across chunk sizes,
 with prefix sharing on and off, and through preemption."""
 
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -660,6 +662,102 @@ def test_paged_mixed_step_ragged_spans_write_only_their_span(params):
     np.testing.assert_array_equal(before[[1, 3, 4]], after[[1, 3, 4]])
     changed = (before[2] != after[2]).any(axis=(-2, -1))
     np.testing.assert_array_equal(changed, [False, True, False, False])
+
+
+# XLA's CPU backend rounds some narrow matmuls (CFG's 64 -> 32 K/V
+# projections among them) differently for different row counts, which
+# would blur "bit-identical" below into "equal to an ulp"; at these widths
+# it does not, so the packed layout must reproduce the padded one exactly
+PACKED_CFG = dataclasses.replace(CFG, n_kv_heads=4)
+
+
+def _packed_step_case(kv_dtype: str, seed: int = 7):
+    """One slot batch of 8 that mixes every kind of row: decodes (slots 0,
+    2, 7), two full prefill chunks (1, and 3, a copy-on-write row whose
+    first page is slot 0's and whose fork point lies inside the span), a
+    tail chunk shorter than the span (5), a one-token prefill (6) and an
+    inert slot (4); the pool full of history (random pages and scales)."""
+    B, pg, MP, S = 8, 4, 6, 8
+    rng = np.random.default_rng(seed)
+    pool = T.init_paged_pool(PACKED_CFG, 1 + B * MP, pg, kv_dtype=kv_dtype)
+    attn = pool["layers"]["attn"]
+    for name in ("k_pages", "v_pages"):
+        a = attn[name]
+        if kv_dtype == "int8":
+            attn[name] = jnp.asarray(rng.integers(-127, 128, a.shape), a.dtype)
+        else:
+            attn[name] = jnp.asarray(rng.standard_normal(a.shape), a.dtype)
+    for name in ("k_scales", "v_scales"):
+        if name in attn:
+            attn[name] = jnp.asarray(
+                rng.uniform(0.005, 0.02, attn[name].shape), jnp.float32)
+    pt = 1 + np.arange(B * MP, dtype=np.int32).reshape(B, MP)
+    pt[3, 0] = pt[0, 0]                        # slot 3 shares slot 0's page
+    use_dev = np.array([1, 0, 1, 0, 0, 0, 0, 1], bool)
+    start = np.array([9, 0, 5, 2, 0, 8, 6, 14], np.int32)
+    span = np.array([1, 8, 1, 8, 0, 3, 1, 1], np.int32)
+    wstart = np.array([0, 0, 0, 4, 0, 0, 0, 0], np.int32)
+    sample = np.array([1, 0, 1, 0, 0, 1, 1, 1], bool)
+    chunks = np.zeros((B, S), np.int32)        # span rows in slot order
+    for i, s in enumerate(np.flatnonzero(~use_dev & (span > 0))):
+        chunks[i, :span[s]] = rng.integers(0, CFG.vocab, span[s])
+    tok = rng.integers(0, CFG.vocab, B).astype(np.int32)
+    args = (tok, use_dev, start, span, pt, wstart, sample)
+    return pool, chunks, [jnp.asarray(a) for a in args]
+
+
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+@pytest.mark.parametrize("attention", ["dense", "kernel"])
+def test_packed_step_matches_every_row_in_the_span_group(attention,
+                                                         kv_dtype):
+    """The mixed step with its span group packed to the bucket of its four
+    prefill rows (R = 4) against the same step at R = B: the same greedy
+    draws, logits equal to fp32 tolerance, and every page but the sink
+    bit-identical — over the dense gather and the Pallas span kernel (in
+    interpret mode here), with bf16 and int8 KV pages."""
+    from repro.core.quant import KV_DTYPE_BYTES
+    from repro.kernels.ops import paged_dispatch
+    from repro.serving import engine as E
+
+    cfg = dataclasses.replace(PACKED_CFG, paged_kernel=attention == "kernel")
+    params = T.init_params(jax.random.PRNGKey(0), cfg)
+    for s in (1, 8):   # both groups take the path under test
+        want = "kernel" if attention == "kernel" else "disabled"
+        assert paged_dispatch(
+            s, cfg.n_heads, cfg.hd, 4, cfg.n_kv_heads,
+            KV_DTYPE_BYTES[kv_dtype], quantized=kv_dtype == "int8",
+            paged_kernel=cfg.paged_kernel) == want
+    pool, chunks, (tok, use_dev, start, span, pt, wstart, sample) = \
+        _packed_step_case(kv_dtype)
+    B = tok.shape[0]
+    temp = jnp.zeros((B,), jnp.float32)
+    keys = jnp.zeros((B, 2), jnp.uint32)
+    logits_fn = jax.jit(E._slot_logits, static_argnames=("cfg",))
+    step_fn = jax.jit(E._mixed_step_body, static_argnames=("cfg",))
+    out = {}
+    for R in (4, B):
+        ck = jnp.asarray(chunks[:R])
+        copy = jax.tree_util.tree_map(jnp.copy, pool)
+        logits, new_pool = logits_fn(params, copy, ck, tok, use_dev, start,
+                                     span, pt, wstart, cfg=cfg)
+        _, sampled, tok_new, _ = step_fn(
+            params, jax.tree_util.tree_map(jnp.copy, pool), ck, tok,
+            use_dev, start, span, pt, wstart, sample, temp, keys, cfg=cfg)
+        out[R] = (np.asarray(logits), new_pool, np.asarray(tok_new))
+    real = np.asarray(span) > 0
+    np.testing.assert_allclose(out[4][0][real], out[B][0][real],
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(out[4][2], out[B][2])
+    assert (out[4][2] != np.asarray(tok))[np.asarray(sample)].any()
+    for a, b, before in zip(jax.tree_util.tree_leaves(out[4][1]),
+                            jax.tree_util.tree_leaves(out[B][1]),
+                            jax.tree_util.tree_leaves(pool)):
+        np.testing.assert_array_equal(np.asarray(a)[:, 1:],
+                                      np.asarray(b)[:, 1:])
+        # the shared page and the inert slot's pages come out as they were
+        for p in [pt[0, 0]] + list(np.asarray(pt[4])):
+            np.testing.assert_array_equal(np.asarray(a)[:, p],
+                                          np.asarray(before)[:, p])
 
 
 def test_cow_copy_pages_device():

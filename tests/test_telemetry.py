@@ -6,6 +6,7 @@ validation), cost-model calibration, per-request lifecycle timestamps
 ``CostModel`` protocol conformance of both bundled cost models, and
 end-to-end stats consistency through a preempting prefix-sharing run."""
 
+import collections
 import json
 import math
 
@@ -523,12 +524,35 @@ def test_engine_spans_land_in_profiler_trace(params, tmp_path, trace):
 
 
 def test_dispatch_span_carries_step_composition(params):
-    eng, _ = _run_contended(params, trace=True)
+    eng, reqs = _run_contended(params, trace=True)
     disp = [e["args"] for e in eng.tracer.events
             if e["ph"] == "X" and e["name"] == "dispatch"]
     assert len(disp) == eng.stats["mixed_steps"]
     for a in disp:
         assert a["bucket"] & (a["bucket"] - 1) == 0        # a power of two
         assert 0 < a["prefill_rows"] + a["decode_rows"] <= a["spans"]
+        # the span group: the row bucket of the prefill rows, each row the
+        # chunk bucket wide; a decode-only step has none and bucket 1
+        if a["prefill_rows"]:
+            assert a["rows"] == 1 << (a["prefill_rows"] - 1).bit_length()
+            assert a["bucket"] == eng.span_bucket == 8
+        else:
+            assert a["rows"] == 0 and a["bucket"] == 1
+        assert a["positions"] == eng.max_slots + a["rows"] * a["bucket"]
+    assert sum(a["positions"] for a in disp) == eng.stats["step_positions"]
     assert sum(a["decode_rows"] for a in disp) == eng.stats["decode_tokens"]
     assert any(a["prefill_rows"] and a["decode_rows"] for a in disp)
+    assert any(a["rows"] == 0 for a in disp)
+    assert {a["rows"] for a in disp} > {0, 1}
+    # the dispatch log lists each step's rows, and tracing does not move it
+    per_step = collections.Counter(step for step, *_ in eng.dispatch_log)
+    assert [per_step[a["step"]] for a in disp] == [
+        a["prefill_rows"] + a["decode_rows"] for a in disp]
+    quiet, quiet_reqs = _run_contended(params)
+
+    def log(e, rs):   # request ids count up across engines: use the order
+        order = {r.req_id: i for i, r in enumerate(rs)}
+        return [(s, order[rid], kind, n) for s, rid, kind, n in e.dispatch_log]
+
+    assert log(quiet, quiet_reqs) == log(eng, reqs)
+    assert quiet.stats["step_positions"] == eng.stats["step_positions"]

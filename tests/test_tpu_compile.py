@@ -171,16 +171,23 @@ def test_paged_span_kernel_compiles(one_chip, kv_dtype, longest):
                  q, pages, pages, *tail)
 
 
-def test_full_width_mixed_step_compiles(one_chip, mosaic):
+@pytest.mark.parametrize("attention", ["dense", "kernel"])
+@pytest.mark.parametrize("rows", [1, 32])
+def test_full_width_mixed_step_compiles(one_chip, mosaic, rows, attention):
     """The engine's mixed step at full CodeQwen width, bf16 weights and KV,
-    Pallas Monarch and paged kernels, 8 slots x 1024 tokens of pool, at the
-    longest span the paged kernel takes: it fits one chip's 16 GiB."""
+    32 slots over 8 x 1024 tokens of pool, its span group of ``rows`` rows
+    at the longest span the paged kernel takes: the dense gather with XLA's
+    Monarch einsums, or the Pallas Monarch and paged kernels.  It fits one
+    chip's 16 GiB."""
     from repro.models import transformer as T
     from repro.serving.engine import _mixed_step_jit
 
-    cfg = dataclasses.replace(
-        CODEQWEN, paged_kernel=True,
-        monarch=dataclasses.replace(CODEQWEN.monarch, backend="pallas"))
+    slots = 32
+    cfg = CODEQWEN
+    if attention == "kernel":
+        cfg = dataclasses.replace(
+            CODEQWEN, paged_kernel=True,
+            monarch=dataclasses.replace(CODEQWEN.monarch, backend="pallas"))
     params = jax.eval_shape(
         lambda k: T.serving_params(T.init_params(k, cfg), cfg),
         jax.random.PRNGKey(0))
@@ -191,14 +198,14 @@ def test_full_width_mixed_step_compiles(one_chip, mosaic):
         lambda a: _sds(one_chip, a.shape, a.dtype), tree)
     span = _largest_kernel_span("bf16")
     i32 = jnp.int32
-    rows = [((SLOTS, span), i32), ((SLOTS,), i32), ((SLOTS,), bool),
-            ((SLOTS,), i32), ((SLOTS,), i32), ((SLOTS, mp), i32),
-            ((SLOTS,), i32), ((SLOTS,), bool), ((SLOTS,), jnp.float32),
-            ((SLOTS, 2), jnp.uint32)]
+    args = [((rows, span), i32), ((slots,), i32), ((slots,), bool),
+            ((slots,), i32), ((slots,), i32), ((slots, mp), i32),
+            ((slots,), i32), ((slots,), bool), ((slots,), jnp.float32),
+            ((slots, 2), jnp.uint32)]
     compiled = _mixed_step_jit.lower(
-        place(params), place(pool), *[_sds(one_chip, s, d) for s, d in rows],
+        place(params), place(pool), *[_sds(one_chip, s, d) for s, d in args],
         cfg=cfg).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    assert ("tpu_custom_call" in compiled.as_text()) == (attention == "kernel")
     ma = compiled.memory_analysis()
     total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
              - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
