@@ -3,9 +3,10 @@
 Once the window has closed, a sample of the requests the engine finished is
 drawn from the seed, always with the longest of them, until it holds
 ``sample_tokens`` served tokens (or every finished request; fewer than
-``min_tokens`` is not correct).  The plain reference (``bench/reference.py``)
-runs once over each prompt followed by its served tokens; at the position
-of every served token it gives the reference's best logit and the logit of
+``min_tokens`` is not correct).  The plain reference (the ``score`` of the
+configuration's family; ``bench/reference.py`` for the dense decoder) runs
+once over each prompt followed by its served tokens; at the position of
+every served token it gives the reference's best logit and the logit of
 the token the engine served.  Per served token the gap is
 
     best reference logit - reference logit of the token the engine served
@@ -45,11 +46,11 @@ def draw_sample(finished: list, seed: int, want_tokens: int) -> list:
     return out
 
 
-def widest_gap(model: dict, weights, sample: list, length: int) -> dict:
-    """Run the reference over every sampled request (padded to ``length``
-    positions); the widest gap and what it was read over."""
-    from bench.reference import score
-
+def widest_gap(score, model: dict, weights, sample: list,
+               length: int) -> dict:
+    """Run the reference ``score`` (a family's) over every sampled request
+    (padded to ``length`` positions); the widest gap and what it was read
+    over."""
     gaps, agree = [], 0
     for prompt, served in sample:
         r = score(model, weights, prompt, served, length)

@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+import typing
 
 import jax
 import jax.numpy as jnp
@@ -23,15 +24,37 @@ DTYPES = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}
 
 def model_config(spec: dict):
     """``ModelConfig`` as the file states it: the registry entry ``arch``
-    with every key of ``model`` replaced (``monarch`` field by field)."""
+    with every key of ``model`` replaced (nested settings field by field)."""
     from repro.configs import get_config
 
-    cfg = get_config(spec["arch"])
-    fields = dict(spec["model"])
-    monarch = fields.pop("monarch", None)
-    if monarch is not None:
-        fields["monarch"] = dataclasses.replace(cfg.monarch, **monarch)
-    return dataclasses.replace(cfg, **fields)
+    return _replaced(get_config(spec["arch"]), spec["model"])
+
+
+def _replaced(obj, fields: dict):
+    """The dataclass ``obj`` with ``fields`` replaced.  A dict given for a
+    dataclass-valued field (``monarch``, ``moe``, ``ssm``) builds it field
+    by field, from the field's value or, where that is None, from its type;
+    a list given for a tuple field becomes a tuple."""
+    hints = typing.get_type_hints(type(obj))
+    return dataclasses.replace(obj, **{
+        name: _value(hints[name], getattr(obj, name), value)
+        for name, value in fields.items()})
+
+
+def _value(hint, current, value):
+    """``value`` as the field of type ``hint`` (now ``current``) takes it."""
+    kinds = [t for t in typing.get_args(hint) if t is not type(None)]
+    kind = kinds[0] if typing.get_origin(hint) is typing.Union else hint
+    if isinstance(value, dict) and dataclasses.is_dataclass(kind):
+        if current is None:
+            hints = typing.get_type_hints(kind)
+            return kind(**{k: _value(hints[k], None, v)
+                           for k, v in value.items()})
+        return _replaced(current, value)
+    if isinstance(value, list) and (kind is tuple
+                                    or typing.get_origin(kind) is tuple):
+        return tuple(value)
+    return value
 
 
 # ---------------------------------------------------------------------------
@@ -129,26 +152,29 @@ def _weight_tree(key, cfg):
             "ln_f": norm(False)}
 
 
-def make_weights(cfg, seed: int):
-    """All weights on the default device, one jitted call, served dtype."""
-    check_layout(cfg)
-    return jax.jit(_weight_tree, static_argnums=1)(seed_key(seed), cfg)
+def make_weights(cfg, seed: int, tree=None):
+    """All weights on the default device, one jitted call, served dtype:
+    ``tree(key, cfg)``, a family's ``weight_tree`` (this module's dense
+    decoder by default)."""
+    tree = tree or _weight_tree
+    check_layout(cfg, tree)
+    return jax.jit(tree, static_argnums=1)(seed_key(seed), cfg)
 
 
-def check_layout(cfg) -> None:
+def check_layout(cfg, tree) -> None:
     """Fail loudly when the program's served weight layout is not the one
-    this module draws (a changed key, shape or dtype)."""
+    ``tree`` draws (a changed key, shape or dtype)."""
     from repro.models import transformer as T
 
     want = jax.eval_shape(
         lambda k: T.serving_params(T.init_params(k, cfg), cfg),
         jax.random.PRNGKey(0))
-    have = jax.eval_shape(lambda k: _weight_tree(k, cfg), seed_key(0))
+    have = jax.eval_shape(lambda k: tree(k, cfg), seed_key(0))
     sig = lambda t: jax.tree_util.tree_map(  # noqa: E731
         lambda a: (a.shape, str(a.dtype)), t)
     if sig(want) != sig(have):
         raise RuntimeError("the program's served weight layout differs from "
-                           "the benchmark's weight maker (bench/model.py)")
+                           f"the benchmark's weight maker ({tree.__module__})")
 
 
 def weight_bytes(tree) -> int:

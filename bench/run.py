@@ -3,11 +3,14 @@
     python bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 Everything about the cell comes from ``BENCHMARK.json`` and the files it
-names: the configuration (``configs[].file``), the mix
-(``bench/traffic/<traffic>.json``), the correctness limits
+names: the configuration (``configs[].file``), its model family
+(``bench/families/<family>.py``, the file's ``family`` key, ``decoder``
+where it has none: weights, reference, work count and scope names), the
+mix (``bench/traffic/<traffic>.json``), the correctness limits
 (``bench/limits/<cell>.json``) and each per-layer metric's reader
 (``bench/metrics/<metric>.py``, or ``<base>.py`` for ``<base>.<group>``).
-A cell, configuration, mix or metric is added by adding files and entries.
+A cell, configuration, family, mix or metric is added by adding files and
+entries.
 
 One run, one process: weights made on the device from the seed; the pool
 sized from the compiled step (``bench/sizing.py``); the engine built with
@@ -15,9 +18,11 @@ its defaults plus the configuration's deployment settings; every span
 bucket the cell dispatches warmed; the mix run unmeasured for its
 ``warmup_s``; then ``--seconds`` measured (``bench/window.py``).  With
 ``--trace 1`` the engine's tracer is on and the profiler traces the last
-seconds of the window; the line then holds the per-layer metrics.  After
-the window: peak device memory, then the engine is freed and the plain
-reference judges a sample of the served tokens (``bench/correct.py``).
+seconds of the window, read under the family's scopes
+(``bench/scopes.py``); the line then holds the per-layer metrics.  After
+the window: peak device memory, then the engine is freed and the family's
+plain reference judges a sample of the served tokens
+(``bench/correct.py``).
 
 Exits non-zero, printing no result, when JAX finds no TPU, fewer chips than
 the cell asks for, a device kind not in ``bench/peaks.json``, or a Pallas
@@ -69,6 +74,7 @@ class Layout:
         self.root = Path(root)
         self.bench = self.root / "bench"
         self.spec = json.loads((self.root / "BENCHMARK.json").read_text())
+        self._modules: dict = {}
 
     def cell(self, name: str) -> dict:
         cells = {w["name"]: w for w in self.spec["workloads"]}
@@ -76,9 +82,11 @@ class Layout:
             raise KeyError(f"no workload {name!r} in BENCHMARK.json")
         cell = cells[name]
         conf = {c["name"]: c for c in self.spec["configs"]}[cell["config"]]
+        config = json.loads((self.root / conf["file"]).read_text())
         return dict(
             cell=cell,
-            config=json.loads((self.root / conf["file"]).read_text()),
+            config=config,
+            family=self.family(config.get("family", "decoder")),
             mix=json.loads((self.bench / "traffic"
                             / f"{cell['traffic']}.json").read_text()),
             limits=json.loads((self.bench / "limits"
@@ -101,14 +109,27 @@ class Layout:
     def reader(self, metric: str):
         d = self.bench / "metrics"
         for stem in (metric, metric.split(".")[0]):
-            path = d / f"{stem}.py"
-            if path.exists():
-                mod = re.sub(r"\W", "_", f"bench_metric_{stem}")
-                spec = importlib.util.spec_from_file_location(mod, path)
-                module = importlib.util.module_from_spec(spec)
-                spec.loader.exec_module(module)
-                return module.read
+            if (d / f"{stem}.py").exists():
+                return self._module("metrics", stem).read
         raise FileNotFoundError(f"no reader for metric {metric!r} in {d}")
+
+    def family(self, name: str):
+        """The model family module ``bench/families/<name>.py``."""
+        if not (self.bench / "families" / f"{name}.py").exists():
+            raise FileNotFoundError(f"no model family {name!r} in "
+                                    f"{self.bench / 'families'}")
+        return self._module("families", name)
+
+    def _module(self, kind: str, stem: str):
+        """``bench/<kind>/<stem>.py`` loaded by path, once per layout."""
+        if (kind, stem) not in self._modules:
+            path = self.bench / kind / f"{stem}.py"
+            mod = re.sub(r"\W", "_", f"bench_{kind}_{stem}")
+            spec = importlib.util.spec_from_file_location(mod, path)
+            module = importlib.util.module_from_spec(spec)
+            spec.loader.exec_module(module)
+            self._modules[kind, stem] = module
+        return self._modules[kind, stem]
 
 
 # ---------------------------------------------------------------------------
@@ -141,7 +162,12 @@ def require_chip(chips: int) -> dict:
 
 def use_compile_cache() -> str:
     """JAX's persistent compilation cache at a fixed path in the checkout
-    (or where ``JAX_COMPILATION_CACHE_DIR`` says); every program cached."""
+    (or where ``JAX_COMPILATION_CACHE_DIR`` says); every program cached.
+    The key takes in the programs' op metadata: without it an executable
+    compiled from a source whose named scopes differ can load in place of
+    the program's own, and a traced run then reads the wrong scopes or
+    none.  Traced and untraced runs compile the same programs, so they
+    share the entries."""
     import jax
 
     path = os.environ.get("JAX_COMPILATION_CACHE_DIR") or str(
@@ -149,6 +175,7 @@ def use_compile_cache() -> str:
     jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     return path
 
 
@@ -222,7 +249,9 @@ def warm_slot_updates(slots: int, pages_per_seq: int) -> None:
 class TracedRun:
     trace: dict | None
     stretch_steps: int
-    stretch_work: list
+    stretch_work: list         # family's step_work of each stretch step
+    stretch_parts: list        # family's step_parts of each stretch step
+    scope_s: dict              # device self time by scope over the stretch
     peaks: dict
     host_step_ms: list
     prefix_hit_tokens: int
@@ -248,9 +277,13 @@ def host_step_ms(events, lo: int, hi: int) -> list[float]:
     return out
 
 
-def traced_context(engine, w, shape, pk, trace_summary, max_slots):
-    from bench.work import step_work, steps_from_log
+def traced_context(engine, w, family, cfg, pk, trace_summary, max_slots):
+    """What the per-layer readers read of a traced run: the window's
+    counters and spans, and the traced stretch's device numbers beside its
+    steps' work counted by the model ``family``."""
+    from bench.work import steps_from_log
 
+    shape = family.shape_of(cfg)
     a, b = w.at_start, w.at_end
     reqs = {rec.req.req_id: rec.req for rec in w.records if rec.req}
     p0, p1 = w.profile["begin"], w.profile["end"]
@@ -261,7 +294,9 @@ def traced_context(engine, w, shape, pk, trace_summary, max_slots):
     return TracedRun(
         trace=trace_summary,
         stretch_steps=p1.mixed_steps - p0.mixed_steps,
-        stretch_work=[step_work(shape, r) for r in rows.values()],
+        stretch_work=[family.step_work(shape, r) for r in rows.values()],
+        stretch_parts=[family.step_parts(shape, r) for r in rows.values()],
+        scope_s=(trace_summary or {}).get("scope_s", {}),
         peaks=pk,
         host_step_ms=host_step_ms(engine.tracer.events, a.step_idx,
                                   b.step_idx),
@@ -301,8 +336,9 @@ def run(args, layout: Layout, chip: bool = True) -> dict:
     import jax
 
     files = layout.cell(args.workload)
-    cell, conf, mix, limits = (files["cell"], files["config"], files["mix"],
-                               files["limits"])
+    cell, conf, mix, limits, family = (files["cell"], files["config"],
+                                       files["mix"], files["limits"],
+                                       files["family"])
     if chip:
         pk = require_chip(cell["chips"])
     else:
@@ -317,14 +353,13 @@ def run(args, layout: Layout, chip: bool = True) -> dict:
 
     from bench import correct, model, sizing, traffic
     from bench import window as W
-    from bench.work import shape_of
     from repro.serving import ContinuousBatchingEngine, SchedulerConfig
 
     cfg = model.model_config(conf)
     dep = conf["deployment"]
     slots, page = dep["max_slots"], dep["page_size"]
     t = time.perf_counter()
-    weights = model.make_weights(cfg, args.seed)
+    weights = model.make_weights(cfg, args.seed, family.weight_tree)
     jax.block_until_ready(weights)
     log(f"[weights] {model.weight_bytes(weights) / 1e9:.3f} GB in {cfg.dtype} "
         f"on device in {time.perf_counter() - t:.1f} s")
@@ -381,11 +416,11 @@ def run(args, layout: Layout, chip: bool = True) -> dict:
               "count": len(jax.devices()), "memory_peak_bytes": mem}
     breakdown = None
     if args.trace:
-        from bench import trace as TR
+        from bench import scopes as SC
 
-        summary = TR.reduce(TR.load(logdir))
+        summary = SC.reduce(SC.load(logdir, family.SCOPES))
         shutil.rmtree(logdir, ignore_errors=True)
-        ctx = traced_context(engine, w, shape_of(cfg), pk, summary, slots)
+        ctx = traced_context(engine, w, family, cfg, pk, summary, slots)
         for m in layout.metrics(args.workload, "per_layer"):
             v = layout.reader(m["name"])(ctx)
             if v is not None:
@@ -411,8 +446,8 @@ def run(args, layout: Layout, chip: bool = True) -> dict:
     gc.collect()
     t = time.perf_counter()
     sample = correct.draw_sample(finished, args.seed, limits["sample_tokens"])
-    reading = correct.widest_gap(conf["model"], weights, sample,
-                                 mix["max_len"])
+    reading = correct.widest_gap(family.score, conf["model"], weights,
+                                 sample, mix["max_len"])
     ok, check = correct.judge(reading, limits)
     log(f"[check] reference over {reading['requests']} requests, "
         f"{reading['tokens']} served tokens in {time.perf_counter() - t:.1f} s; "

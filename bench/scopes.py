@@ -4,7 +4,8 @@
 names and the harness's three host spans.  This module adds what the
 program's names give (``serving/tracing.py`` states them as a contract):
 
-* each device operation's scope: the innermost of ``SCOPES`` in the
+* each device operation's scope: the innermost of the family's scope
+  names (a family's ``SCOPES``, ``bench/families``) in the
   ``/``-separated HLO ``op_name`` that the trace carries for it (the
   ``OP_NAME_STAT`` stat of its event's metadata), ``-`` for none;
 * the program's host spans ``PROGRAM_SPANS`` (the engine's tracer, on the
@@ -29,17 +30,16 @@ from typing import Optional
 
 from bench import trace as TR
 
-SCOPES = ("linear", "attention", "unembed", "embed", "norm", "sample")
 PROGRAM_SPANS = ("step", "plan", "admit", "dispatch", "sync", "harvest")
 OP_NAME_STAT = "tf_op"
 NO_SCOPE = "-"
 
 
-def scope_of(op_name: str) -> str:
-    """Innermost program scope of an HLO ``op_name`` path (a trace's
-    ``tf_op`` appends ``:<op type>``, empty for JAX)."""
+def scope_of(op_name: str, scopes) -> str:
+    """Innermost of the program's ``scopes`` in an HLO ``op_name`` path (a
+    trace's ``tf_op`` appends ``:<op type>``, empty for JAX)."""
     path = op_name.rsplit(":", 1)[0].split("/")
-    return next((p for p in reversed(path) if p in SCOPES), NO_SCOPE)
+    return next((p for p in reversed(path) if p in scopes), NO_SCOPE)
 
 
 def is_compile(name: str) -> bool:
@@ -47,10 +47,13 @@ def is_compile(name: str) -> bool:
     return "compile" in name.lower()
 
 
-def load(logdir: str) -> dict:
-    """``bench.trace.load``'s lists, the device operations' names carrying
-    their scope, plus ``program``: the program's spans and XLA's compile
-    events on any host line, as ``(name, start_ns, duration_ns)``."""
+def load(logdir: str, scopes) -> dict:
+    """The newest ``.xplane.pb`` under ``logdir`` as lists of
+    ``(name, start_ns, duration_ns)``: ``devices``, each device plane's
+    operations (line ``OPS_LINE``), named ``<instruction> <opcode>
+    @<scope>``, the scope the innermost of ``scopes``; ``host``, the
+    harness's spans ``HOST_SPANS``; ``program``, the program's spans and
+    XLA's compile events, on any host line."""
     from jax.profiler import ProfileData
 
     paths = sorted(glob.glob(os.path.join(logdir, "**", "*.xplane.pb"),
@@ -64,7 +67,7 @@ def load(logdir: str) -> dict:
         if plane.name.startswith("/device:"):
             names = op_name.get(plane.name, {})
             ops = [(f"{TR.short_name(e.name)} @"
-                    f"{scope_of(names.get(e.name, ''))}",
+                    f"{scope_of(names.get(e.name, ''), scopes)}",
                     e.start_ns, e.duration_ns)
                    for line in plane.lines if line.name == TR.OPS_LINE
                    for e in line.events]

@@ -121,6 +121,12 @@ def size_pool(cfg, weights, slots: int, page_size: int, max_len: int,
         if total is not None and total <= budget:
             break
         n = int(n * 0.97)
+    # Forget what these compiles traced.  The engine would reuse the traced
+    # pieces, whose op metadata points here, and the persistent cache,
+    # keyed with that metadata, would hold programs that the next run,
+    # which reads the pool size from CACHE_DIR, never builds: it would
+    # compile them anew.
+    jax.clear_caches()
     res = dict(n_pages=n, step_bytes=total, limit_bytes=limit,
                bytes_per_page=per_page, span=span)
     log(f"[size] pool {n} pages of {page_size} (worst case {full}); largest "

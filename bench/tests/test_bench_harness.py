@@ -38,6 +38,7 @@ def test_metric_added_as_a_file(layout):
     assert layout.reader("tiny_counter.backlog")(None) == 42.0
     read = layout.reader("batch_occupancy.backlog")
     ctx = R.TracedRun(trace=None, stretch_steps=0, stretch_work=[],
+                      stretch_parts=[], scope_s={},
                       peaks={}, host_step_ms=[], prefix_hit_tokens=0,
                       prompt_tokens_admitted=0, batch_sum=6.0,
                       batch_count=2, max_slots=4)
@@ -57,13 +58,33 @@ def test_metrics_of_a_cell(layout):
 
 
 def test_benchmark_json_metrics_have_readers():
+    """Every per-layer metric has a reader and moves a metric its cells
+    report; a ``<part>_roofline`` reads a scope of each cell's family that
+    the family's count splits out."""
+    from bench import model
+
     layout = R.Layout(ROOT)
     for m in layout.spec["per_layer"]:
         assert callable(layout.reader(m["name"]))
+        base = m["name"].split(".")[0]
         for cell in m["workloads"]:
             moves = {e["name"]: e for e in layout.spec["end_to_end"]}
             e = moves[m["moves"]]
             assert "workloads" not in e or cell in e["workloads"]
+            if base.endswith("_roofline"):
+                part = base[:-len("_roofline")]
+                files = layout.cell(cell)
+                family = files["family"]
+                shape = family.shape_of(model.model_config(files["config"]))
+                assert part in family.SCOPES
+                assert part in family.step_parts(shape, [(0, 1, True)])
+                assert m["unit"] == "%" and m["source"] == "device_trace"
+    rooflines = {m["name"] for m in layout.metrics("codeqwen7b.chat_backlog",
+                                                   "per_layer")
+                 if "_roofline" in m["name"]}
+    assert rooflines == {"linear_roofline.backlog",
+                         "attention_roofline.backlog",
+                         "unembed_roofline.backlog"}
     for w in layout.spec["workloads"]:
         files = layout.cell(w["name"])
         assert files["mix"]["max_len"] > 0

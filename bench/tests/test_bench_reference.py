@@ -8,6 +8,7 @@ import numpy as np
 import pytest
 
 from bench import correct, model
+from bench.reference import score
 
 
 def reference_sizes(cfg) -> dict:
@@ -55,7 +56,8 @@ def test_reference_agrees_with_engine_fp32(reduced):
     d_model 128 stack is ~1e-6 of logits of order 1)."""
     weights = model.make_weights(reduced, 7)
     served = serve(reduced, weights, prompts_for(reduced), 12)
-    r = correct.widest_gap(reference_sizes(reduced), weights, served, 128)
+    r = correct.widest_gap(score, reference_sizes(reduced), weights, served,
+                           128)
     assert r["tokens"] == 24
     assert r["argmax_agreement"] == 1.0
     assert r["max_logit_gap"] <= 1e-4
@@ -68,7 +70,7 @@ def test_reference_bounds_bf16_engine(reduced):
     cfg = dataclasses.replace(reduced, dtype="bfloat16")
     weights = model.make_weights(cfg, 8)
     served = serve(cfg, weights, prompts_for(cfg, 1), 12)
-    r = correct.widest_gap(reference_sizes(cfg), weights, served, 128)
+    r = correct.widest_gap(score, reference_sizes(cfg), weights, served, 128)
     assert r["max_logit_gap"] <= 0.1
 
 
@@ -77,7 +79,7 @@ def test_altered_token_is_caught(reduced):
     logits' spread, far above the bf16 bound."""
     weights = model.make_weights(reduced, 9)
     (p, out), = serve(reduced, weights, prompts_for(reduced)[:1], 6)
-    r = correct.widest_gap(
-        reference_sizes(reduced), weights, [(p, out[:3] + [(out[3] + 1) % 500]
-                                             + out[4:])], 128)
+    altered = out[:3] + [(out[3] + 1) % 500] + out[4:]
+    r = correct.widest_gap(score, reference_sizes(reduced), weights,
+                           [(p, altered)], 128)
     assert r["max_logit_gap"] > 0.1

@@ -11,26 +11,30 @@ import pytest
 
 from bench import scopes as SC
 from bench import trace as TR
+from bench.families.decoder import SCOPES
 
 DATA = Path(__file__).resolve().parent / "data"
 
 
 def test_scope_of_op_names():
-    assert SC.scope_of("jit(_mixed_step_jit)/while/body/closed_call/"
-                       "attention/linear/kqp,...kp->...kq/dot_general") \
+    step = "jit(_mixed_step_jit)/while/body/closed_call/"
+    of = lambda name: SC.scope_of(name, SCOPES)  # noqa: E731
+    assert of(step + "attention/linear/kqp,...kp->...kq/dot_general") \
         == "linear"
-    assert SC.scope_of("jit(_mixed_step_jit)/while/body/closed_call/"
-                       "attention/gather") == "attention"
-    assert SC.scope_of("jit(_mixed_step_jit)/sample/cond/reduce") == "sample"
-    assert SC.scope_of("jit(_mixed_step_jit)/while/body/closed_call/"
-                       "attention/gather:") == "attention"
-    assert SC.scope_of("jit(_mixed_step_jit)/while/body/closed_call/sub:") \
+    assert of(step + "attention/gather") == "attention"
+    assert of("jit(_mixed_step_jit)/sample/cond/reduce") == "sample"
+    assert of(step + "attention/gather:") == "attention"
+    assert of(step + "sub:") == SC.NO_SCOPE
+    assert of("jit(_mixed_step_jit)/jit(take_along_axis)/gather") \
         == SC.NO_SCOPE
-    assert SC.scope_of("jit(_mixed_step_jit)/jit(take_along_axis)/gather") \
-        == SC.NO_SCOPE
-    assert SC.scope_of("") == SC.NO_SCOPE
+    assert of("") == SC.NO_SCOPE
     # a scope is a whole path component, never a part of one
-    assert SC.scope_of("jit(f)/linear_ish/attention_mask") == SC.NO_SCOPE
+    assert of("jit(f)/linear_ish/attention_mask") == SC.NO_SCOPE
+    # the names are the family's: other names read the same path otherwise
+    assert SC.scope_of(step + "attention/linear/dot_general",
+                       ("attention", "experts")) == "attention"
+    assert SC.scope_of(step + "experts/linear/dot_general",
+                       ("attention", "experts")) == "experts"
 
 
 def hand_trace():
@@ -135,7 +139,7 @@ def test_scopes_read_from_event_metadata(tmp_path):
     d.mkdir(parents=True)
     (d / "h.xplane.pb").write_bytes(
         ProfileData.text_proto_to_serialized_xspace(XSPACE))
-    ev = SC.load(str(tmp_path))
+    ev = SC.load(str(tmp_path), SCOPES)
     assert ev["devices"] == [[("fusion.1 fusion @linear", 1005.0, 3.0),
                               ("copy.2 copy @sample", 1009.0, 1.0),
                               ("while.3 while @-", 1011.0, 2.0)]]
@@ -172,7 +176,74 @@ def test_recorded_v5e_steps_by_scope():
     assert all(" @" in n for n, _ in r["device_ops"])
     assert sum(r["scope_s"].values()) == pytest.approx(r["busy_s"], rel=0.01)
     assert {"linear", "attention", "unembed", "norm"} <= set(r["scope_s"])
-    assert set(r["scope_s"]) <= set(SC.SCOPES) | {SC.NO_SCOPE}
+    assert set(r["scope_s"]) <= set(SCOPES) | {SC.NO_SCOPE}
     spans = set(TR.HOST_SPANS) | set(SC.PROGRAM_SPANS)
     assert r["idle_gaps"] and {n for n, _ in r["idle_gaps"]} <= spans
     assert {n for n, _, _ in ev["program"]} <= set(SC.PROGRAM_SPANS)
+
+
+def test_recorded_v5e_steps_through_the_wired_path():
+    """The recorded steps read as a ``--trace 1`` run reads them
+    (``bench.run``: ``scopes.reduce`` under the cell's family, then
+    ``traced_context``): scope times and each step's parts filled, the
+    three rooflines in (0, 105] %, and the device numbers those of
+    ``bench.trace``.  The fixture holds no dispatch log; its two steps'
+    rows stand in for those of a padded step of the time: 30 decoding
+    slots at about 850 positions, one 64-token prefill chunk and its
+    4-token tail."""
+    import types
+
+    from bench import model
+    from bench import run as R
+    from bench.work import peaks
+
+    layout = R.Layout(Path(__file__).resolve().parents[2])
+    cell = "codeqwen7b.chat_backlog"
+    files = layout.cell(cell)
+    family = files["family"]
+    cfg = model.model_config(files["config"])
+    ev = recorded_steps()
+    summary = SC.reduce(ev)
+    req = lambda i, n: types.SimpleNamespace(  # noqa: E731
+        req_id=i, prompt=list(range(n)), num_cached_tokens=0,
+        t_admitted=-1.0)
+    reqs = {i: req(i, 800 + 3 * i) for i in range(30)}
+    reqs.update({i: req(i, 68) for i in (30, 31)})
+    log = [(1, i, "prefill", 800 + 3 * i) for i in range(30)]
+    log += [(9, 31, "prefill", 64)]
+    for step in (11, 12):
+        log += [(step, i, "decode", 1) for i in range(30)]
+    log += [(11, 30, "prefill", 64), (12, 31, "prefill", 4)]
+    snap = lambda step, mixed: types.SimpleNamespace(  # noqa: E731
+        step_idx=step, mixed_steps=mixed, prefix_hit_tokens=0,
+        batch_sum=64.0 * step, batch_count=2 * step)
+    steps = [p for p in ev["program"] if p[0] == "step"]
+    engine = types.SimpleNamespace(
+        dispatch_log=log,
+        tracer=types.SimpleNamespace(events=[
+            {"ph": "X", "name": "step", "dur": d / 1e3,
+             "args": {"step": 11 + i}} for i, (_, _, d) in enumerate(steps)]))
+    w = types.SimpleNamespace(
+        records=[types.SimpleNamespace(req=r) for r in reqs.values()],
+        start=0.0, end=1.0, at_start=snap(10, 10), at_end=snap(12, 12),
+        profile={"begin": snap(10, 10), "end": snap(12, 12)})
+    ctx = R.traced_context(engine, w, family, cfg, peaks("TPU v5 lite"),
+                           summary, 32)
+    assert ctx.scope_s == summary["scope_s"]
+    assert {"linear", "attention", "unembed"} <= set(ctx.scope_s)
+    assert len(ctx.stretch_parts) == ctx.stretch_steps == 2
+    assert all(set(p) == {"linear", "attention", "unembed"}
+               for p in ctx.stretch_parts)
+    read = {m["name"]: layout.reader(m["name"])(ctx)
+            for m in layout.metrics(cell, "per_layer")}
+    for part in ("linear", "attention", "unembed"):
+        assert 0 < read[f"{part}_roofline.backlog"] <= 105
+    unscoped = dict(ev, devices=[[(n.rpartition(" @")[0], s, d)
+                                  for n, s, d in ops]
+                                 for ops in ev["devices"]])
+    base = TR.reduce(unscoped)
+    assert read["step_device_ms.backlog"] == 1e3 * base["busy_s"] / 2
+    assert read["device_idle_share.backlog"] \
+        == 100.0 * (1.0 - base["busy_s"] / base["window_s"])
+    assert 0 < read["step_mfu.backlog"] <= 105
+    assert read["host_ms_per_step.backlog"] > 0

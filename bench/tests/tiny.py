@@ -48,9 +48,12 @@ LIMITS = {"compare": {"mean_logit_gap": {"max": 0.001}}, "sample_tokens": 96,
 
 def write(root: Path, extra_metrics: dict | None = None) -> Path:
     """A checkout-like tree under ``root``: BENCHMARK.json, the bench
-    package's metric readers, and the tiny files, found by name."""
+    package's metric readers and model families, and the tiny files, found
+    by name."""
     b = root / "bench"
-    shutil.copytree(BENCH / "metrics", b / "metrics")
+    for sub in ("metrics", "families"):
+        shutil.copytree(BENCH / sub, b / sub,
+                        ignore=shutil.ignore_patterns("__pycache__"))
     for sub, name, data in (("configs", "tiny", CONFIG),
                             ("traffic", "tiny_backlog", BACKLOG),
                             ("traffic", "tiny_sessions", SESSIONS),

@@ -1,9 +1,10 @@
 """From a profiler trace to device numbers.
 
-``load`` reads the ``.xplane.pb`` that ``jax.profiler`` wrote into plain
-lists of ``(name, start_ns, duration_ns)``: the device's operations (line
-"XLA Ops" of each ``/device:`` plane) and the harness's host spans (its
-``TraceAnnotation`` names, on any host line).  ``reduce`` turns them into:
+``bench.scopes.load`` reads the ``.xplane.pb`` that ``jax.profiler`` wrote
+into plain lists of ``(name, start_ns, duration_ns)``: the device's
+operations (line "XLA Ops" of each ``/device:`` plane) and the harness's
+host spans (its ``TraceAnnotation`` names ``HOST_SPANS``, on any host
+line).  ``reduce`` turns them into:
 
 * the stretch: from the first to the last end of a harness span;
 * ``busy_s``: the union of the device's operation intervals inside it,
@@ -18,8 +19,6 @@ lists of ``(name, start_ns, duration_ns)``: the device's operations (line
 
 from __future__ import annotations
 
-import glob
-import os
 import re
 from typing import Optional
 
@@ -62,29 +61,6 @@ def self_times(ops, lo: float, hi: float) -> dict:
             out[parent[0]] -= min(e, parent[1]) - s
         stack.append((name, e))
     return out
-
-
-def load(logdir: str, host_names=HOST_SPANS) -> dict:
-    from jax.profiler import ProfileData
-
-    paths = sorted(glob.glob(os.path.join(logdir, "**", "*.xplane.pb"),
-                             recursive=True))
-    if not paths:
-        raise FileNotFoundError(f"no .xplane.pb under {logdir}")
-    data = ProfileData.from_file(paths[-1])
-    devices, host = [], []
-    for plane in data.planes:
-        if plane.name.startswith("/device:"):
-            ops = [(short_name(e.name), e.start_ns, e.duration_ns)
-                   for line in plane.lines if line.name == OPS_LINE
-                   for e in line.events]
-            if ops:
-                devices.append(ops)
-        elif plane.name.startswith("/host:"):
-            host.extend((e.name, e.start_ns, e.duration_ns)
-                        for line in plane.lines for e in line.events
-                        if e.name in host_names)
-    return {"devices": devices, "host": host}
 
 
 def union(intervals, lo: float, hi: float) -> list[tuple[float, float]]:
