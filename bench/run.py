@@ -12,11 +12,13 @@ mix (``bench/traffic/<traffic>.json``), the correctness limits
 A cell, configuration, family, mix or metric is added by adding files and
 entries.
 
-One run, one process: weights made on the device from the seed; the pool
-sized from the compiled step (``bench/sizing.py``); the engine built with
-its defaults plus the configuration's deployment settings; every span
-bucket the cell dispatches warmed; the mix run unmeasured for its
-``warmup_s``; then ``--seconds`` measured (``bench/window.py``).  With
+One run, one process: the configuration's ``deployment`` settings checked
+against the engine's constructor (every key is passed to it by name);
+weights made on the device from the seed; the pool sized from the
+engine's own compiled step (``bench/sizing.py``); the engine built; every
+program the cell dispatches warmed; the mix run unmeasured for its
+``warmup_s`` or ``warmup_steps``; then ``--seconds`` measured
+(``bench/window.py``).  With
 ``--trace 1`` the engine's tracer is on and the profiler traces the last
 seconds of the window, read under the family's scopes
 (``bench/scopes.py``); the line then holds the per-layer metrics.  After
@@ -55,6 +57,10 @@ import numpy as np  # noqa: E402
 
 # seconds of the window's end the profiler traces in a --trace 1 run
 PROFILE_S = 3.0
+# engine arguments the harness sets itself (from the mix, the pool sizing,
+# --trace; the per-layer readers read the engine's metrics), which a
+# configuration's deployment may not set
+HARNESS_SETS = ("max_len", "n_pages", "pool_bytes", "trace", "metrics")
 
 
 def log(msg: str) -> None:
@@ -187,17 +193,50 @@ def peak_memory() -> int:
 
 
 # ---------------------------------------------------------------------------
-# warm-up: every span bucket (and COW batch) the cell can dispatch
+# the engine's settings
 # ---------------------------------------------------------------------------
 
 
-def warm(engine, buckets, page_size: int, vocab: int, shares: bool,
-         rng) -> None:
-    """Serve tiny requests through the engine's own entry so each span
-    bucket compiles (or loads) before the window; with prefix-sharing
-    traffic also the copy-on-write batches of 1, 2 and 4 forks.  Then the
-    small eager updates of the engine's slot arrays, for every number of
-    rows a step can touch (``warm_slot_updates``)."""
+def engine_settings(deployment: dict) -> dict:
+    """A configuration's ``deployment``, each key an argument of the
+    engine's constructor, passed to it by name.  Exits naming a key the
+    constructor does not take, or one the harness sets itself."""
+    import inspect
+
+    from repro.serving import ContinuousBatchingEngine
+
+    params = inspect.signature(ContinuousBatchingEngine).parameters
+    for key in deployment:
+        p = params.get(key)
+        if p is None or p.kind is not p.KEYWORD_ONLY:
+            sys.exit(f"bench: deployment setting {key!r} is no argument of "
+                     "ContinuousBatchingEngine")
+        if key in HARNESS_SETS:
+            sys.exit(f"bench: deployment setting {key!r} is set by the "
+                     "harness, not by a configuration")
+    return dict(deployment)
+
+
+# ---------------------------------------------------------------------------
+# warm-up: every program the cell can dispatch
+# ---------------------------------------------------------------------------
+
+
+def span_buckets(chunk_size: int) -> list[int]:
+    """Prompt lengths the warm-up serves: powers of two up to the bucket
+    of ``chunk_size``, so one chunk of each length goes through admission,
+    the mixed step (one span width, a program per row bucket, all
+    compiled at its first prefill) and eviction."""
+    top = 1 << (chunk_size - 1).bit_length()
+    return [1 << i for i in range(top.bit_length())]
+
+
+def warm(engine, buckets, vocab: int, shares: bool, rng) -> None:
+    """Serve tiny requests through the engine's own entry, one of each
+    length in ``buckets``, so the step's programs compile (or load) before
+    the window; with prefix-sharing traffic also the copy-on-write batches
+    of 1, 2 and 4 forks.  Then the scatters into the engine's slot arrays
+    for every number of rows a step can touch (``warm_slot_scatters``)."""
     from repro.serving import SamplingParams
 
     def serve(prompts):
@@ -210,34 +249,34 @@ def warm(engine, buckets, page_size: int, vocab: int, shares: bool,
         if b + 2 <= engine.max_len:
             serve([rng.integers(0, vocab, b, dtype=np.int32)])
     if shares:
-        base = rng.integers(0, vocab, page_size + page_size // 2,
-                            dtype=np.int32)
+        page = engine.page_size
+        base = rng.integers(0, vocab, page + page // 2, dtype=np.int32)
         serve([base])
         for k in (1, 2, 4):
             serve([np.concatenate([base, rng.integers(0, vocab, 3,
                                                       dtype=np.int32)])
                    for _ in range(k)])
-    warm_slot_updates(engine.max_slots, engine.max_pages_per_seq)
+    warm_slot_scatters(engine)
 
 
-def warm_slot_updates(slots: int, pages_per_seq: int) -> None:
-    """Between steps the engine scatters host rows into its slot arrays
-    (page tables, temperatures, fork points, PRNG keys) for the k slots a
-    step admitted or whose page table grew; each k is its own small
-    program.  Run the same scatters here for k = 1..slots, so none of them
-    compiles inside the window (the window counts compilations)."""
+def warm_slot_scatters(engine) -> None:
+    """Between steps the engine scatters host rows into its per-slot
+    device arrays for the k slots a step admitted or whose page table
+    grew; each k is its own small program.  Run that scatter for k =
+    1..slots into every device array the engine holds with a row per slot,
+    so none compiles inside the window (the window counts compilations).
+    The arrays are found on the engine, not named here: a per-slot array a
+    later engine adds is warmed too.  The results are dropped."""
     import jax
-    import jax.numpy as jnp
 
-    targets = ((jnp.zeros((slots,), jnp.float32), np.float32, ()),
-               (jnp.zeros((slots,), jnp.int32), np.int32, ()),
-               (jnp.zeros((slots, 2), jnp.uint32), np.uint32, (2,)),
-               (jnp.zeros((slots, pages_per_seq), jnp.int32), np.int32,
-                (pages_per_seq,)))
-    for k in range(1, slots + 1):
-        idx = np.arange(k)
-        for arr, dt, tail in targets:
-            jax.block_until_ready(arr.at[idx].set(np.zeros((k, *tail), dt)))
+    slots = engine.max_slots
+    for arr in vars(engine).values():
+        if not (isinstance(arr, jax.Array) and arr.ndim
+                and arr.shape[0] == slots):
+            continue
+        for k in range(1, slots + 1):
+            rows = np.zeros((k, *arr.shape[1:]), arr.dtype)
+            jax.block_until_ready(arr.at[np.arange(k)].set(rows))
 
 
 # ---------------------------------------------------------------------------
@@ -339,6 +378,7 @@ def run(args, layout: Layout, chip: bool = True) -> dict:
     cell, conf, mix, limits, family = (files["cell"], files["config"],
                                        files["mix"], files["limits"],
                                        files["family"])
+    settings = engine_settings(conf["deployment"])
     if chip:
         pk = require_chip(cell["chips"])
     else:
@@ -353,36 +393,34 @@ def run(args, layout: Layout, chip: bool = True) -> dict:
 
     from bench import correct, model, sizing, traffic
     from bench import window as W
-    from repro.serving import ContinuousBatchingEngine, SchedulerConfig
+    from repro.serving import ContinuousBatchingEngine
 
     cfg = model.model_config(conf)
-    dep = conf["deployment"]
-    slots, page = dep["max_slots"], dep["page_size"]
     t = time.perf_counter()
     weights = model.make_weights(cfg, args.seed, family.weight_tree)
     jax.block_until_ready(weights)
     log(f"[weights] {model.weight_bytes(weights) / 1e9:.3f} GB in {cfg.dtype} "
         f"on device in {time.perf_counter() - t:.1f} s")
-    chunk = SchedulerConfig().chunk_size
-    size = sizing.size_pool(cfg, weights, slots, page, mix["max_len"], chunk,
-                            log=log)
+    size = sizing.size_pool(cfg, weights, settings, mix["max_len"], log=log)
     # the control (not a benchmark run): the program's own lower-precision
     # path, int8 Monarch weights and int8 KV pages, judged like the program
     control = (dict(quantize="int8", kv_dtype="int8") if args.control
                else {})
     engine = ContinuousBatchingEngine(
-        cfg, weights, max_slots=slots, page_size=page, max_len=mix["max_len"],
-        n_pages=size["n_pages"], trace=bool(args.trace), **control)
+        cfg, weights, max_len=mix["max_len"], n_pages=size["n_pages"],
+        trace=bool(args.trace), **{**settings, **control})
     compiles = W.CompileCounter()
     rng = np.random.default_rng([int(args.seed) % 2**64, 1])
     t = time.perf_counter()
-    warm(engine, sizing.span_buckets(chunk), page, cfg.vocab,
-         mix["loop"] == "open", rng)
-    log(f"[warm] span buckets {sizing.span_buckets(chunk)} in "
+    buckets = span_buckets(engine.scheduler.cfg.chunk_size)
+    warm(engine, buckets, cfg.vocab, mix["loop"] == "open", rng)
+    log(f"[warm] prompts of {buckets} tokens in "
         f"{time.perf_counter() - t:.1f} s, {compiles.n} backend compiles")
     warmup = {k: mix[k] for k in ("warmup_s", "warmup_steps") if k in mix}
     requests = traffic.generate(mix, args.seed, cfg.vocab,
                                 mix.get("warmup_s", 0) + args.seconds)
+    log(f"[traffic] {len(requests)} requests, digest "
+        f"{traffic.digest(requests)}")
 
     profiler, logdir = None, None
     if args.trace:
@@ -420,7 +458,8 @@ def run(args, layout: Layout, chip: bool = True) -> dict:
 
         summary = SC.reduce(SC.load(logdir, family.SCOPES))
         shutil.rmtree(logdir, ignore_errors=True)
-        ctx = traced_context(engine, w, family, cfg, pk, summary, slots)
+        ctx = traced_context(engine, w, family, cfg, pk, summary,
+                             engine.max_slots)
         for m in layout.metrics(args.workload, "per_layer"):
             v = layout.reader(m["name"])(ctx)
             if v is not None:

@@ -142,3 +142,73 @@ def test_refuses_with_only_the_benchmark_files(tmp_path):
     p = _run_bench(tmp_path)
     assert p.returncode != 0
     assert p.stdout.strip() == ""
+
+
+def _layout_with(tmp_path, deployment: dict):
+    """The tiny layout with the configuration's deployment replaced."""
+    root = tiny.write(tmp_path)
+    conf = dict(tiny.CONFIG, deployment=deployment)
+    (root / "bench" / "configs" / "tiny.json").write_text(json.dumps(conf))
+    return R.Layout(root)
+
+
+@pytest.mark.parametrize("key", ["no_such_setting", "max_len", "cfg"])
+def test_deployment_key_refused_before_weights(tmp_path, monkeypatch, key):
+    from bench import model
+
+    def no_weights(*a, **k):
+        raise AssertionError("weights made before the settings were read")
+
+    monkeypatch.setattr(model, "make_weights", no_weights)
+    layout = _layout_with(tmp_path, dict(tiny.CONFIG["deployment"],
+                                         **{key: 1}))
+    args = R.parse(["--workload", "tiny.backlog", "--seed", "5",
+                    "--seconds", "1", "--trace", "0"])
+    with pytest.raises(SystemExit, match=repr(key)):
+        R.run(args, layout, chip=False)
+
+
+def test_deployment_settings_reach_the_engine(tmp_path, capsys):
+    """Every deployment key goes to the engine by name: a chunk size the
+    harness never names reaches the scheduler, and the warm-up serves
+    prompts up to it."""
+    layout = _layout_with(tmp_path, dict(tiny.CONFIG["deployment"],
+                                         chunk_size=32))
+    args = R.parse(["--workload", "tiny.backlog", "--seed", "5",
+                    "--seconds", "1", "--trace", "0"])
+    out = R.run(args, layout, chip=False)
+    assert out["correct"]
+    assert "[warm] prompts of [1, 2, 4, 8, 16, 32] tokens" in \
+        capsys.readouterr().err
+    assert R.engine_settings({"chunk_size": 32}) == {"chunk_size": 32}
+
+
+def test_warm_up_leaves_no_admission_to_compile():
+    """After the harness's warm-up, admitting any number of requests at
+    once, up to every slot, compiles nothing."""
+    import jax
+    import numpy as np
+
+    from bench import model
+    from bench.window import CompileCounter
+    from repro.serving import ContinuousBatchingEngine, SamplingParams
+
+    jax.clear_caches()
+    cfg = model.model_config(tiny.CONFIG)
+    engine = ContinuousBatchingEngine(cfg, model.make_weights(cfg, 7),
+                                      max_len=64, **tiny.CONFIG["deployment"])
+    rng = np.random.default_rng(7)
+    R.warm(engine, R.span_buckets(engine.scheduler.cfg.chunk_size),
+           cfg.vocab, False, rng)
+    counter = CompileCounter()
+    try:
+        for k in range(1, engine.max_slots + 1):
+            for i in range(k):
+                engine.add_request(
+                    rng.integers(0, cfg.vocab, 5 + 9 * i, dtype=np.int32),
+                    SamplingParams(max_new_tokens=3 + 4 * i))
+            engine.run()
+    finally:
+        counter.close()
+    assert engine.stats["finished"] >= 10
+    assert counter.n == 0

@@ -159,3 +159,113 @@ def test_oversized_request_is_refused():
     m["max_len"] = 1024
     with pytest.raises(ValueError, match="exceeds max_len"):
         traffic.generate(m, 1, VOCAB, 10.0)
+
+
+# request digests (``traffic.digest``) of the generator as it stood before
+# mixes could hold kinds: a mix without kinds draws the same sizes, order
+# and token ids
+DIGESTS = {
+    ("chat_backlog", 1):
+        "cc944c9c2d9f6dee1090813bf0b490153965df6f33ba25949359f56dbe5806d4",
+    ("chat_backlog", 4000018201):
+        "843c3b2a35e6f067cf4334cd831891e1dcc7eb1b128001e851f23e359904b269",
+    ("repo_completion", 1):
+        "5e829db0616d7cade44786d02eb98b34efcad183a29c3bcf81826ed02bc4bdb8",
+    ("repo_completion", 4000018201):
+        "c682d1af2db63d4992d8e1015155a3b4666457032ea2b8493e131573190f2809",
+}
+
+
+@pytest.mark.parametrize("name,seed", list(DIGESTS))
+def test_mix_without_kinds_draws_as_before(name, seed):
+    reqs = traffic.generate(mix(name), seed, VOCAB, 60.0)
+    assert traffic.digest(reqs) == DIGESTS[name, seed]
+
+
+# short and long requests in one queue (the shape of ROADMAP W5)
+SHORT = {"dist": "uniform_int", "min": 100, "max": 199}
+LONG = {"dist": "lognormal", "median": 4000, "sigma": 0.3, "min": 2000,
+        "max": 8000}
+OUT = {"dist": "uniform_int", "min": 16, "max": 64}
+KINDS_BACKLOG = {
+    "name": "short_and_long", "loop": "backlog", "requests": 96,
+    "shape_seed": 11, "block": 8,
+    "kinds": [{"share": 0.75, "prompt_tokens": SHORT, "output_tokens": OUT},
+              {"share": 0.25, "prompt_tokens": LONG,
+               "output_tokens": {"dist": "uniform_int", "min": 500,
+                                 "max": 1000}}],
+    "max_len": 9216}
+
+
+def _is_long(r) -> bool:
+    return len(r.prompt) >= LONG["min"]
+
+
+def test_kinds_hold_their_shares_in_every_block():
+    reqs = traffic.generate(KINDS_BACKLOG, 2**35 + 1, VOCAB, 60.0)
+    assert len(reqs) == 96
+    b = KINDS_BACKLOG["block"]
+    for i in range(0, len(reqs), b):
+        block = reqs[i:i + b]
+        longs = [r for r in block if _is_long(r)]
+        assert len(longs) == 2
+        assert all(500 <= r.max_new_tokens <= 1000 for r in longs)
+        assert all(SHORT["min"] <= len(r.prompt) <= SHORT["max"]
+                   and OUT["min"] <= r.max_new_tokens <= OUT["max"]
+                   for r in block if not _is_long(r))
+    # each kind draws stratified blocks of its own count: every 6 short
+    # prompts hold the same sizes, every 2 long ones too
+    shorts = [len(r.prompt) for r in reqs if not _is_long(r)]
+    longs = [len(r.prompt) for r in reqs if _is_long(r)]
+    for sizes, n in ((shorts, 6), (longs, 2)):
+        first = sorted(sizes[:n])
+        assert all(sorted(sizes[i:i + n]) == first
+                   for i in range(0, len(sizes), n))
+    # where the long ones sit in a block is shuffled by the shape seed
+    where = {tuple(j for j, r in enumerate(reqs[i:i + b]) if _is_long(r))
+             for i in range(0, len(reqs), b)}
+    assert len(where) > 1
+    # and the seed changes the tokens, not the work
+    other = traffic.generate(KINDS_BACKLOG, 3, VOCAB, 60.0)
+    assert [(len(r.prompt), r.max_new_tokens) for r in other] == \
+        [(len(r.prompt), r.max_new_tokens) for r in reqs]
+
+
+def test_kinds_of_sessions():
+    m = dict(LATER["repo_completion"], block=4)
+    ctx = m.pop("context_tokens")
+    out = m.pop("output_tokens")
+    m["kinds"] = [
+        {"share": 0.5, "context_tokens": ctx, "output_tokens": out},
+        {"share": 0.5, "context_tokens": {"dist": "uniform_int",
+                                          "min": 64, "max": 128},
+         "output_tokens": {"dist": "uniform_int", "min": 256, "max": 512}}]
+    reqs = traffic.generate(m, 9, VOCAB, 300.0)
+    by = collections.defaultdict(list)
+    for r in reqs:
+        by[r.session].append(r)
+    sessions = [by[s] for s in sorted(by)]
+    second = [rs[0].max_new_tokens >= 256 for rs in sessions]
+    for rs, s in zip(sessions, second):     # a session keeps its kind
+        assert all((r.max_new_tokens >= 256) == s for r in rs)
+    assert len(second) >= 8
+    for i in range(0, len(second) - 3, 4):
+        assert sum(second[i:i + 4]) == 2
+
+
+@pytest.mark.parametrize("kinds,match", [
+    ([{"share": 0.5, "prompt_tokens": SHORT, "output_tokens": OUT},
+      {"share": 0.3, "prompt_tokens": LONG, "output_tokens": OUT}],
+     "give 6 of a block of 8"),
+    ([{"share": 0.99, "prompt_tokens": SHORT, "output_tokens": OUT},
+      {"share": 0.01, "prompt_tokens": LONG, "output_tokens": OUT}],
+     "is no request"),
+    ([{"share": 1.0, "prompt_tokens": SHORT, "output_tokens": OUT,
+       "gap_s": OUT}], "a kind has"),
+])
+def test_kinds_that_do_not_fill_a_block_are_refused(kinds, match):
+    with pytest.raises(ValueError, match=match):
+        traffic.generate(dict(KINDS_BACKLOG, kinds=kinds), 1, VOCAB, 60.0)
+    with pytest.raises(ValueError, match="not beside them"):
+        traffic.generate(dict(KINDS_BACKLOG, output_tokens=OUT), 1, VOCAB,
+                         60.0)

@@ -18,12 +18,21 @@ Two loops:
   each request's prompt is the previous prompt plus ``edit_tokens`` new
   tokens, so all but the edit is a prefix another request committed.
 
+A mix may give ``kinds`` in place of its ``prompt_tokens`` (backlog) or
+``context_tokens`` (open) and ``output_tokens``: a list of ``{"share",
+"prompt_tokens" | "context_tokens", "output_tokens"}``, one per request
+kind (short and long in one queue).  Every block of ``block`` requests (or
+sessions) then holds round(share x block) of each kind, in an order
+shuffled by ``shape_seed``, and each kind draws its sizes from stratified
+blocks of its own count.  A session keeps its kind for all its requests.
+
 Token ids are uniform over the model's vocabulary.  Decoding is greedy.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import hashlib
 import json
 import math
 import statistics
@@ -59,15 +68,12 @@ def quantile(dist: dict, u: float) -> float:
     return min(max(v, dist.get("min", -math.inf)), dist.get("max", math.inf))
 
 
-class Stratified:
-    """An endless stream of draws: blocks of the ``block`` mid-quantiles
-    (i + 0.5) / block, each block shuffled by ``rng``."""
+class Shuffled:
+    """An endless stream of draws: blocks of ``values``, each block
+    shuffled by ``rng``."""
 
-    def __init__(self, dist: dict, block: int, rng: np.random.Generator,
-                 integer: bool = True):
-        self._vals = [quantile(dist, (i + 0.5) / block) for i in range(block)]
-        if integer:
-            self._vals = [int(round(v)) for v in self._vals]
+    def __init__(self, values: list, rng: np.random.Generator):
+        self._vals = list(values)
         self._rng = rng
         self._buf: list = []
 
@@ -78,21 +84,76 @@ class Stratified:
         return self._buf.pop()
 
 
+class Stratified(Shuffled):
+    """Blocks of the ``block`` mid-quantiles (i + 0.5) / block of ``dist``,
+    each block shuffled by ``rng``."""
+
+    def __init__(self, dist: dict, block: int, rng: np.random.Generator,
+                 integer: bool = True):
+        vals = [quantile(dist, (i + 0.5) / block) for i in range(block)]
+        super().__init__([int(round(v)) for v in vals] if integer else vals,
+                         rng)
+
+
+class Kinds:
+    """The request kinds of a mix: ``size()`` gives the next request's (or
+    session's) kind and its first size, ``output(kind)`` an output length
+    of that kind.  A mix without ``kinds`` is one kind of its own keys;
+    one kind draws no order, so such a mix draws exactly as before kinds
+    existed."""
+
+    def __init__(self, mix: dict, first: str, rng: np.random.Generator):
+        block = mix["block"]
+        kinds = mix.get("kinds")
+        if kinds is None:
+            kinds = [{"share": 1.0, first: mix[first],
+                      "output_tokens": mix["output_tokens"]}]
+        elif first in mix or "output_tokens" in mix:
+            raise ValueError(f"a mix with kinds gives {first} and "
+                             "output_tokens in each kind, not beside them")
+        counts = [round(k["share"] * block) for k in kinds]
+        for k, n in zip(kinds, counts):
+            if set(k) != {"share", first, "output_tokens"}:
+                raise ValueError(f"a kind has share, {first} and "
+                                 f"output_tokens, not {sorted(k)}")
+            if n < 1:
+                raise ValueError(f"share {k['share']} of a block of {block} "
+                                 "is no request")
+        if sum(counts) != block:
+            raise ValueError(f"shares {[k['share'] for k in kinds]} give "
+                             f"{sum(counts)} of a block of {block}")
+        self._kind = (Shuffled([i for i, n in enumerate(counts)
+                                for _ in range(n)], rng)
+                      if len(kinds) > 1 else lambda: 0)
+        self._first = [Stratified(k[first], n, rng)
+                       for k, n in zip(kinds, counts)]
+        self._output = [Stratified(k["output_tokens"], n, rng)
+                        for k, n in zip(kinds, counts)]
+
+    def size(self) -> tuple[int, int]:
+        kind = self._kind()
+        return kind, self._first[kind]()
+
+    def output(self, kind: int) -> int:
+        return self._output[kind]()
+
+
 def generate(mix: dict, seed: int, vocab: int, horizon_s: float) -> list[Req]:
     """Requests of ``mix`` due before ``horizon_s``, sorted by due time:
     sizes and due times from the mix's ``shape_seed``, token ids from
     ``seed``."""
     rng = np.random.default_rng(int(seed) % 2**64)
     shapes = np.random.default_rng(mix["shape_seed"])
-    block = mix["block"]
-    draw = lambda key, integer=True: Stratified(  # noqa: E731
-        mix[key], block, shapes, integer)
     if mix["loop"] == "backlog":
-        prompt, output = draw("prompt_tokens"), draw("output_tokens")
-        reqs = [Req(0.0, rng.integers(0, vocab, prompt(), dtype=np.int32),
-                    output(), i) for i in range(mix["requests"])]
+        kinds = Kinds(mix, "prompt_tokens", shapes)
+        reqs = []
+        for i in range(mix["requests"]):
+            kind, prompt = kinds.size()
+            reqs.append(Req(0.0, rng.integers(0, vocab, prompt,
+                                              dtype=np.int32),
+                            kinds.output(kind), i))
     elif mix["loop"] == "open":
-        reqs = _sessions(mix, rng, shapes, vocab, horizon_s, draw)
+        reqs = _sessions(mix, rng, shapes, vocab, horizon_s)
     else:
         raise ValueError(f"unknown loop {mix['loop']!r}")
     for r in reqs:
@@ -103,22 +164,37 @@ def generate(mix: dict, seed: int, vocab: int, horizon_s: float) -> list[Req]:
     return sorted(reqs, key=lambda r: (r.due_s, r.session))
 
 
-def _sessions(mix, rng, shapes, vocab, horizon_s, draw) -> list[Req]:
+def digest(reqs: list[Req]) -> str:
+    """A fingerprint of a request list: every due time, size, session and
+    token id, in order."""
+    h = hashlib.sha256()
+    for r in reqs:
+        h.update(repr((r.due_s, r.max_new_tokens, r.session,
+                       len(r.prompt))).encode())
+        h.update(r.prompt.tobytes())
+    return h.hexdigest()
+
+
+def _sessions(mix, rng, shapes, vocab, horizon_s) -> list[Req]:
+    block = mix["block"]
+    draw = lambda key, integer=True: Stratified(  # noqa: E731
+        mix[key], block, shapes, integer)
     arrival = Stratified({"dist": "exponential",
                           "mean": 1.0 / mix["session_rate_per_s"]},
-                         mix["block"], shapes, integer=False)
-    context, n_reqs = draw("context_tokens"), draw("requests_per_session")
+                         block, shapes, integer=False)
+    kinds = Kinds(mix, "context_tokens", shapes)
+    n_reqs = draw("requests_per_session")
     gap, edit = draw("gap_s", integer=False), draw("edit_tokens")
-    output = draw("output_tokens")
     reqs, t, sid = [], 0.0, 0
     while t < horizon_s:
-        prompt = rng.integers(0, vocab, context(), dtype=np.int32)
+        kind, context = kinds.size()
+        prompt = rng.integers(0, vocab, context, dtype=np.int32)
         due = t
         for _ in range(n_reqs()):
             prompt = np.concatenate(
                 [prompt, rng.integers(0, vocab, edit(), dtype=np.int32)])
             if due < horizon_s:
-                reqs.append(Req(due, prompt, output(), sid))
+                reqs.append(Req(due, prompt, kinds.output(kind), sid))
             due += gap()
         sid += 1
         t += arrival()
